@@ -2,7 +2,12 @@
 
 On TPU the Pallas kernel runs natively; elsewhere it runs in interpret mode
 (the kernel body executes on CPU — used by the correctness sweeps). Shapes
-that do not tile evenly fall back to the jnp oracle.
+that do not tile evenly raise ``ValueError``; the jnp oracle
+``ref.reference`` is never substituted in silence.
+
+Layout: the kernel runs on head-major ``[B, H, S, hd]`` views, so each tile
+is ``(rows, hd)`` with whole-array last dims; the ``(1, hd)``-per-head tiles
+of the ``[B, S, H, hd]`` layout were refused by the TPU compiler.
 """
 
 from __future__ import annotations
@@ -11,7 +16,6 @@ from functools import partial
 
 import jax
 
-from . import ref
 from .flash_attention import flash_attention_fwd
 
 
@@ -24,13 +28,14 @@ def _on_tpu() -> bool:
 def flash_attention(q, k, v, *, q_positions, k_positions, causal=True,
                     window=0, logit_softcap=0.0, block_q=128, block_k=128,
                     interpret=None):
-    B, Sq, H, hd = q.shape
+    Sq, H = q.shape[1], q.shape[2]
     Skv = k.shape[1]
     bq, bk = min(block_q, Sq), min(block_k, Skv)
     if Sq % bq or Skv % bk or H % k.shape[2]:
-        return ref.reference(q, k, v, q_positions=q_positions,
-                             k_positions=k_positions, causal=causal,
-                             window=window, logit_softcap=logit_softcap)
+        raise ValueError(
+            f"flash_attention: q {q.shape} / kv {k.shape} do not tile into "
+            f"blocks ({bq}, {bk}) with whole head groups; call "
+            f"flash_attention.ref.reference for this shape")
     if interpret is None:
         interpret = not _on_tpu()
     return flash_attention_fwd(

@@ -1,12 +1,16 @@
 """Jit'd public wrapper for the paged-attention decode kernel.
 
 On TPU the Pallas kernel runs natively; elsewhere it runs in interpret mode
-(the kernel body executes on CPU — used by the correctness sweeps).  Lanes
-whose head grouping does not divide evenly fall back to the gather-based
-jnp oracle.  The oracle is also the path the serving engine uses off-TPU:
-its arithmetic is bitwise-identical to the dense cache path, which the
-engine's token-identity guarantee depends on (the online-softmax kernel is
-only tolerance-close).
+(the kernel body executes on CPU — used by the correctness sweeps).  A head
+grouping that does not divide evenly raises ``ValueError``: the gather-based
+``ref.reference`` oracle is never substituted in silence, and callers that
+want it (the serving engine's default path, whose token identity needs its
+bitwise dense-equal arithmetic) call it explicitly.
+
+Layout: each grid step DMAs one whole physical page ``[bs, KV, hd]`` and
+serves every q head of the lane from it, so a tile's last two dims are the
+pool's own ``(KV, hd)`` — the per-head ``(1, hd)`` tile it replaced was
+refused by the TPU compiler for any head count that is not a multiple of 8.
 """
 
 from __future__ import annotations
@@ -15,7 +19,6 @@ from functools import partial
 
 import jax
 
-from . import ref
 from .paged_attention import paged_attention_fwd
 
 
@@ -32,13 +35,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     block_tables: [B, max_blocks]; context_lens: [B]; window: sliding-window
     width (0 = global). Returns [B, H, hd].
     """
-    B, H, hd = q.shape
-    KV = k_pages.shape[2]
+    H, KV = q.shape[1], k_pages.shape[2]
     if H % KV:
-        return ref.reference(
-            q[:, None], k_pages, v_pages, block_tables, context_lens,
-            q_positions=(context_lens - 1)[:, None],
-            logit_softcap=logit_softcap, window=window)[:, 0]
+        raise ValueError(
+            f"paged_attention: {H} q heads do not group evenly over {KV} kv "
+            f"heads (q {q.shape}, pages {k_pages.shape}); call "
+            f"paged_attention.ref.reference for this shape")
     if interpret is None:
         interpret = not _on_tpu()
     return paged_attention_fwd(

@@ -4,7 +4,14 @@ On TPU the Pallas kernel runs natively; elsewhere it runs in interpret mode
 (the kernel body executes on CPU — used by the correctness sweeps against
 ``ref.reference``).  xs: [B, S, nh, hd]; dt: [B, S, nh] (post-softplus);
 A: [nh] (negative); B_mat/C_mat: [B, S, ns]; D: [nh].  Returns
-(y [B, S, nh, hd], final inter-chunk state [B, nh, hd, ns]).
+(y [B, S, nh, hd], final inter-chunk state [B, nh, hd, ns]).  The scan
+starts from a zero state; it has no seeded-state entry, and model code that
+carries a state in must not ask for it (``ssm.ssd_layer`` raises).
+
+Layout: the per-head scalars A and D sit whole in SMEM (their rank-1
+``(1,)`` VMEM tiles were refused by the TPU compiler), x/y run head-major
+``[B, nh, S, hd]``, and dt rides as a column and a row per chunk so the
+in-kernel cumsum is two masked reductions.
 """
 
 from __future__ import annotations

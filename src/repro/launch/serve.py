@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.core import Topology, adapt_plan, compile_plan
+from repro.launch.device import device_info, enable_compile_cache
 from repro.models import lm
 from repro.serve import ContinuousEngine, Engine, Router, SamplingParams
 
@@ -97,7 +98,7 @@ def _router(args, cfg, params, key):
     roles = "/".join(r.role for r in router.replicas)
     print(f"[router] {args.arch}: {len(results)} requests over "
           f"{args.replicas} replicas ({roles}), {total} tokens in "
-          f"{dt:.2f}s ({total / dt:.1f} tok/s)")
+          f"{dt:.2f}s host wall-clock ({total / dt:.1f} tok/s)")
     print(f"[router] placement={fs['routed_per_replica']} "
           f"handoffs={fs['handoffs']} "
           f"transferred_blocks={fs['transferred_blocks']} "
@@ -122,6 +123,7 @@ def _router(args, cfg, params, key):
             print(f"[adapt] step time {out.trace.step_times[0]*1e3:.2f}ms "
                   f"-> {out.trace.step_times[-1]*1e3:.2f}ms "
                   f"({out.trace.improvement:.1%} under fleet load)")
+    return router, results
 
 
 def _static(args, cfg, params, key):
@@ -137,8 +139,9 @@ def _static(args, cfg, params, key):
     dt = time.time() - t0
     toks = args.batch * args.max_new
     print(f"[serve] {args.arch}: generated {out.shape} in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s batched)")
+          f"host wall-clock ({toks/dt:.1f} tok/s batched)")
     print("first sequence:", out[0].tolist())
+    return eng, out
 
 
 def _continuous(args, cfg, params, key):
@@ -174,9 +177,9 @@ def _continuous(args, cfg, params, key):
     tel = eng.telemetry
     total = sum(len(v) for v in results.values())
     print(f"[serve-cb] {args.arch}: {len(results)} requests, {total} tokens "
-          f"in {dt:.2f}s ({total/dt:.1f} tok/s)")
+          f"in {dt:.2f}s host wall-clock ({total/dt:.1f} tok/s)")
     if not results:
-        return
+        return eng, results
     print(f"[serve-cb] occupancy={tel.occupancy():.2f} "
           f"cache_pressure={tel.cache_pressure():.2f} "
           f"peak={tel.peak_cache_pressure():.2f} "
@@ -235,9 +238,12 @@ def _continuous(args, cfg, params, key):
             print(f"[adapt] adapted t_step {adapted.step_time*1e3:.2f}ms "
                   f"cut {adapted.cut_bytes:.3e}B (trace replayable: "
                   f"{adapted.assignment == trace.replay(plan.assignment)})")
+    return eng, results
 
 
 def main(argv=None):
+    """Run one launch; returns ``(engine or router, results)``."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -315,6 +321,7 @@ def main(argv=None):
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    print(f"[device] {device_info()}")
     key = jax.random.PRNGKey(args.seed)
     params = lm.init_params(cfg, key, jnp.float32 if args.reduced
                             else jnp.bfloat16)
@@ -322,11 +329,10 @@ def main(argv=None):
         if not args.continuous:
             raise SystemExit("--replicas requires --continuous (the router "
                              "fans a request trace over engine replicas)")
-        _router(args, cfg, params, key)
-    elif args.continuous:
-        _continuous(args, cfg, params, key)
-    else:
-        _static(args, cfg, params, key)
+        return _router(args, cfg, params, key)
+    if args.continuous:
+        return _continuous(args, cfg, params, key)
+    return _static(args, cfg, params, key)
 
 
 if __name__ == "__main__":

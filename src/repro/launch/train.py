@@ -4,13 +4,15 @@ Wires every substrate together: config -> planner (the paper's compiler) ->
 sharding rules -> jit'd train step -> data pipeline -> checkpoint manager ->
 telemetry + scheduling-assistant runtime.
 
-On this CPU container it runs reduced configs end-to-end (examples/ use it);
-on a real pod the same entrypoint runs the full configs — the mesh shape and
-``--multi-pod`` flag are the only changes.
+On the CPU it runs reduced configs end to end (examples/ use it); on TPU
+the same entry point runs published widths — ``--layers`` cuts depth, and
+``--data-mesh``/``--model-mesh`` (or ``--multi-pod``) shard the state.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --reduced --steps 100 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+    PYTHONPATH=src python -m repro.launch.train --arch minicpm-2b \
+        --layers 4 --steps 3 --data-mesh 2 --model-mesh 2   # four chips
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import Topology, compile_plan
 from repro.core.placement import ShardingRules
 from repro.data import DataConfig, make_pipeline
+from repro.launch.device import device_info, enable_compile_cache
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import lm
 from repro.models.config import ShapeConfig
@@ -36,10 +39,15 @@ from repro.train import TrainStepConfig, make_train_step
 
 
 def main(argv=None):
+    """Train; returns ``{"params", "opt", "losses"}`` after the last step
+    (``losses`` maps step -> loss, read before that step's update)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
                     help="reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="cut depth to N layers, widths as published")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -59,6 +67,9 @@ def main(argv=None):
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = cfg.replace(n_layers=args.layers)
+    print(f"[device] {device_info()}")
 
     # --- the paper's compiler pass: plan the placement -----------------------
     # compile() goes through the on-disk plan cache, so re-launching the
@@ -113,18 +124,21 @@ def main(argv=None):
                        frontend_dim=cfg.frontend_dim if cfg.frontend else 0),
             start_step=start)
         telem = Telemetry()
+        losses = {}
 
         for i in range(start, args.steps):
             step_i, raw = data.next() if hasattr(data, "next") else (i, data.batch_at(i))
             batch = {kk: jnp.asarray(vv) for kk, vv in raw.items()}
             t0 = time.time()
             params, opt, m = jit_step(params, opt, batch, jnp.asarray(step_i))
+            losses[step_i] = float(m["loss"])
             dt = time.time() - t0
-            telem.record(step_i, dt, float(m["loss"]))
+            telem.record(step_i, dt, losses[step_i])
             if step_i % args.log_every == 0 or step_i == args.steps - 1:
-                print(f"[step {step_i:5d}] loss={float(m['loss']):.4f} "
+                print(f"[step {step_i:5d}] loss={losses[step_i]:.4f} "
                       f"gnorm={float(m['grad_norm']):.3f} "
-                      f"lr={float(m['lr']):.2e} {dt*1e3:.0f}ms")
+                      f"lr={float(m['lr']):.2e} {dt*1e3:.0f}ms host "
+                      f"wall-clock")
             if mgr and step_i and step_i % args.ckpt_every == 0:
                 mgr.save(step_i, {"params": params, "opt": opt},
                          meta={"arch": args.arch})
@@ -133,8 +147,9 @@ def main(argv=None):
                      meta={"arch": args.arch})
         if hasattr(data, "close"):
             data.close()
-    print(f"[done] median step {telem.median_ms():.0f}ms; "
+    print(f"[done] median step {telem.median_ms():.0f}ms host wall-clock; "
           f"stragglers detected: {telem.n_stragglers()}")
+    return {"params": params, "opt": opt, "losses": losses}
 
 
 if __name__ == "__main__":

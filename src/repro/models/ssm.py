@@ -149,14 +149,17 @@ def ssd_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
         dt = jnp.where(jnp.arange(S)[None, :, None] < valid_len, dt, 0.0)
     A = -jnp.exp(p["A_log"])
 
-    if impl == "pallas" and init_state is None:
+    if impl == "pallas":
+        if init_state is not None:
+            # the Pallas scan always starts from a zero state
+            raise ValueError(
+                "ssd_layer: impl='pallas' cannot continue from a carried "
+                "state (prefill with a cache); use impl='chunked'")
         from repro.kernels.ssd_scan import ops as ssd_ops
         y, final_state = ssd_ops.ssd_scan(xs, dt, A, B_mat, C_mat, p["D"],
                                           chunk=cfg.ssm_chunk)
     else:
-        # chunk-carried prefill threads the previous chunks' state in; the
-        # Pallas scan has no seeded-state entry point, so carried prefills
-        # take the jnp chunked core (identical semantics)
+        # chunk-carried prefill threads the previous chunks' state in
         y, final_state = _ssd_chunked_core(xs, dt, A, B_mat, C_mat, p["D"],
                                            cfg.ssm_chunk,
                                            init_state=init_state)

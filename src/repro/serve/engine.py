@@ -51,6 +51,7 @@ as precomputed embeddings.
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from dataclasses import dataclass, field
@@ -529,10 +530,6 @@ class ContinuousEngine:
                                                   moe_lossless=True))
         self._prefill_b = jax.jit(make_bucketed_prefill_step(self.cfg,
                                                              self.impl))
-        # reusable zeroed single-request cache fed to every full prefill
-        # (jax arrays are immutable, so sharing the template across
-        # admissions is safe and saves an alloc+zero per request)
-        self._fresh = lm.init_cache(self.cfg, 1, self._kv_total, self.dtype)
         self._toks = jnp.zeros((self.n_slots,), jnp.int32)
         self._pos = jnp.zeros((self.n_slots,), jnp.int32)
         # per-lane sampling state, refreshed at admission: base PRNG keys
@@ -730,8 +727,32 @@ class ContinuousEngine:
         self._insert_p = jax.jit(paged_insert)
         self._lane_set = jax.jit(lane_set)
 
+    @functools.cached_property
+    def _fresh(self) -> dict:
+        """Reusable zeroed single-request cache fed to every full prefill
+        and to the recurrent-state reset (jax arrays are immutable, so
+        sharing the template across admissions is safe and saves an
+        alloc+zero per request).  Built on first use: chunked prefill of an
+        attention-only arch never needs it, and at full width it is a whole
+        dense ``kv_len`` cache of device memory."""
+        return lm.init_cache(self.cfg, 1, self._kv_total, self.dtype)
+
+    @property
+    def _caches(self) -> dict:
+        return self._cache_tree
+
+    @_caches.setter
+    def _caches(self, tree: dict) -> None:
+        # every jitted step returns fresh pools: the allocator's stores must
+        # follow at once, or they pin the superseded version and keep a
+        # second full copy of the pools alive on the device
+        self._cache_tree = tree
+        self._rebind_stores()
+
     def _rebind_stores(self) -> None:
-        """Hand the post-step pool arrays back to the allocator's stores."""
+        """Hand the current pool arrays to the allocator's stores."""
+        if not self.allocator.stores:
+            return
         for (_, keys, leaf), store in zip(
                 lm.paged_cache_leaves(self.cfg, self._caches),
                 self.allocator.stores):
@@ -752,7 +773,6 @@ class ContinuousEngine:
         if not self.prefix_cache:
             raise ValueError("export_prefix_blocks requires prefix_cache "
                              "(the handoff is keyed by the content index)")
-        self._rebind_stores()
         gstores = [s for s, g in zip(self.allocator.stores,
                                      self.allocator.store_groups)
                    if g == "global"]
@@ -1361,8 +1381,6 @@ class ContinuousEngine:
             self._record_step(now, t0, decoding, prefills, chunks, new_tokens)
             self._now = now + 1
             steps += 1
-        if self.paged:
-            self._rebind_stores()
         return results
 
     def _record_step(self, now: int, t0: float, active_slots, prefills: int,

@@ -41,15 +41,13 @@ def test_readme_and_docs_exist():
 
 def test_documented_modules_import():
     """Commands shown in README/docs refer to these modules; a rename must
-    update the docs (the link checker cannot see module paths).  The launch
-    CLIs are covered by their own (slow) dry-run tests — importing
-    repro.launch pulls in mesh helpers that need a newer jax than some
-    environments carry, so only the serving/kernel modules are probed
-    here."""
+    update the docs (the link checker cannot see module paths)."""
     import importlib
     for mod in ("repro.serve", "repro.kernels.paged_attention",
-                "repro.kernels.flash_attention", "repro.runtime.telemetry"):
+                "repro.kernels.flash_attention", "repro.runtime.telemetry",
+                "repro.launch.serve", "repro.launch.train"):
         importlib.import_module(mod)
     for path in ("src/repro/launch/serve.py", "src/repro/launch/train.py",
-                 "benchmarks/serve_throughput.py", "examples/quickstart.py"):
+                 "benchmarks/serve_throughput.py", "examples/quickstart.py",
+                 "chip_smoke.py"):
         assert (ROOT / path).is_file(), path
