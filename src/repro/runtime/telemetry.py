@@ -11,15 +11,20 @@ engine records slot occupancy, KV-cache block pressure and step latency each
 decode step, and ``assistant_callback`` turns that record into the
 ``telemetry=`` feed of ``core.assistants.run_adaptation`` — live serving
 interference (instead of the analytical simulator alone) driving the §3
-out-box protocol.
+out-box protocol.  ``StepSpans`` is what the engine fills while a step
+runs: one profiler span per phase of the step, and the phase's host
+seconds and work for the step's ``ServeStep``.
 """
 
 from __future__ import annotations
 
 import statistics
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -87,6 +92,63 @@ class ServeStep:
     drafted: int = 0
     accepted: int = 0
     rewound_tokens: int = 0
+    # where the step's host time went and what it did (see StepSpans):
+    # perf_counter at its start and end, host seconds per phase span,
+    # the rids admitted, the prompt chunks run ((rid, start, valid,
+    # total) rows), and the lanes decoded ((rid, position) of the row
+    # each wrote)
+    t0: float = 0.0
+    t1: float = 0.0
+    phases: dict = field(default_factory=dict)
+    admitted: tuple = ()
+    chunks: tuple = ()
+    decoded: tuple = ()
+
+
+class _Phase:
+    """A profiler span that adds its host seconds to ``phases[name]``."""
+
+    __slots__ = ("_phases", "_name", "_span", "_t0")
+
+    def __init__(self, phases: dict, name: str, span: TraceAnnotation):
+        self._phases, self._name, self._span = phases, name, span
+
+    def __enter__(self) -> None:
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._phases[self._name] = self._phases.get(self._name, 0.0) + dt
+
+
+class StepSpans:
+    """The engine step in progress: its phase spans and what it did.
+
+    ``span(name, **meta)`` opens a ``jax.profiler.TraceAnnotation`` named
+    ``name`` whose stats are ``step`` and ``meta``; a running profiler
+    writes it on its host plane, on the device trace's clock (about a
+    microsecond when none runs).  On exit the span's ``perf_counter``
+    seconds are added to ``phases[name]``.  The engine opens phases one
+    after another inside one ``engine.step`` span, never one inside
+    another, so a phase's seconds are its self time and ``phases`` sums
+    to at most the step's.
+    """
+
+    __slots__ = ("step", "t0", "phases", "admitted", "chunks", "decoded")
+
+    def __init__(self, step: int):
+        self.step = step
+        self.t0 = time.perf_counter()
+        self.phases: dict = {}
+        self.admitted: list = []
+        self.chunks: list = []
+        self.decoded: list = []
+
+    def span(self, name: str, **meta) -> _Phase:
+        return _Phase(self.phases, name,
+                      TraceAnnotation(name, step=self.step, **meta))
 
 
 @dataclass
@@ -110,7 +172,6 @@ class ServeTelemetry:
     def __post_init__(self):
         self.steps = deque(self.steps, maxlen=self.history)
         self._total_tokens = 0
-        self._busy_seconds = 0.0
         self._peak_pressure = 0.0
         self._max_concurrency = 0
         self._peak_resident_bytes = 0
@@ -128,7 +189,6 @@ class ServeTelemetry:
         """Drop all recorded steps and whole-run aggregates."""
         self.steps.clear()
         self._total_tokens = 0
-        self._busy_seconds = 0.0
         self._peak_pressure = 0.0
         self._max_concurrency = 0
         self._peak_resident_bytes = 0
@@ -152,7 +212,9 @@ class ServeTelemetry:
                     prefix_lookup_tokens: int = 0,
                     shared_saved_bytes: int = 0,
                     cached_blocks: int = 0, drafted: int = 0,
-                    accepted: int = 0, rewound_tokens: int = 0) -> None:
+                    accepted: int = 0, rewound_tokens: int = 0,
+                    t0: float = 0.0, t1: float = 0.0, phases: dict = None,
+                    admitted=(), chunks=(), decoded=()) -> None:
         self.steps.append(ServeStep(
             step=step, seconds=seconds, active_slots=tuple(active_slots),
             n_slots=n_slots, blocks_in_use=blocks_in_use, n_blocks=n_blocks,
@@ -165,11 +227,13 @@ class ServeTelemetry:
             prefix_lookup_tokens=prefix_lookup_tokens,
             shared_saved_bytes=shared_saved_bytes,
             cached_blocks=cached_blocks, drafted=drafted,
-            accepted=accepted, rewound_tokens=rewound_tokens))
+            accepted=accepted, rewound_tokens=rewound_tokens,
+            t0=t0, t1=t1, phases=dict(phases or {}),
+            admitted=tuple(admitted), chunks=tuple(chunks),
+            decoded=tuple(decoded)))
         # chunk work units are not emitted tokens — only completed prefills
         # (one greedy token each) and decode tokens count
         self._total_tokens += new_tokens + prefills
-        self._busy_seconds += seconds
         if n_blocks:
             self._peak_pressure = max(self._peak_pressure,
                                       blocks_in_use / n_blocks)
@@ -285,11 +349,6 @@ class ServeTelemetry:
         the quantity the router benchmark gates when comparing co-located
         against disaggregated prefill/decode."""
         return self._starved_decode_steps
-
-    def tokens_per_sec(self) -> float:
-        if self._busy_seconds <= 0:
-            return 0.0
-        return self._total_tokens / self._busy_seconds
 
     # -- assistant bridge (paper §3) -------------------------------------------
     def device_interference(self, k: int) -> list:

@@ -77,6 +77,7 @@ Two layers:
 
 from __future__ import annotations
 
+import math
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Optional
@@ -205,10 +206,11 @@ class PagedKVStore:
 
     @property
     def block_bytes(self) -> int:
-        """HBM bytes one block id pins across all layers (both pools)."""
-        per_k, per_v = self.k_pages[:, 0], self.v_pages[:, 0]
-        return per_k.size * per_k.dtype.itemsize + \
-            per_v.size * per_v.dtype.itemsize
+        """HBM bytes one block id pins across all layers (both pools),
+        from the pools' shapes and types alone: indexing a pool would run
+        a program on the device."""
+        return sum(math.prod(p.shape) // p.shape[1] * p.dtype.itemsize
+                   for p in (self.k_pages, self.v_pages))
 
     @property
     def capacity_bytes(self) -> int:

@@ -61,10 +61,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.models import lm
 from repro.models.config import ModelConfig, ShapeConfig
-from repro.runtime.telemetry import ServeTelemetry
+from repro.runtime.telemetry import ServeTelemetry, StepSpans
 
 from . import sampling as sampling_mod
 from .cache import (BlockAllocator, CacheConfig, CacheExhausted, CacheLayout,
@@ -549,6 +550,8 @@ class ContinuousEngine:
         # (preemptions, hit_tokens, lookup_tokens) at the last recorded
         # step — _record_step reports per-step deltas of these ledgers
         self._stats_last = (0, 0, 0)
+        # the step in progress (``run()`` makes one per step)
+        self._spans = StepSpans(0)
 
         if self.paged:
             self._init_paged()
@@ -575,6 +578,8 @@ class ContinuousEngine:
             self._insert = jax.jit(admit_update)
             self._caches = lm.init_slot_caches(self.cfg, self.n_slots,
                                                self._kv_total, self.dtype)
+        # fixed by the pools' shapes and the layout: reckoned once
+        self._capacity_bytes = self.allocator.capacity_bytes()
 
     @staticmethod
     def decode_shape_for(kv_len: int, n_slots: int) -> ShapeConfig:
@@ -1027,49 +1032,58 @@ class ContinuousEngine:
         C = self.prefill_chunk
         start = skip + done * C    # prefix-cache hit: skip cached positions
         total = item.shape[0]
-        piece = item[start:start + C]
-        valid = piece.shape[0]                 # real rows in this slice
-        if valid < C:                          # pad final chunk to C
-            piece = jnp.zeros((C,) + item.shape[1:],
-                              item.dtype).at[:valid].set(piece)
-        if self._has_window:
-            # slide the ring to cover this slice; rows behind the slice's
-            # FIRST query keep their window (freed only once fully behind)
-            fresh, freed = self.allocator.extend_window(
-                slot, min(start + C, total), first_query_pos=start)
-            if fresh or freed:
-                self._refresh_row(slot, "window")
-        last = total - 1 - start               # only valid on the final chunk
-        tok, self._caches = self._chunk(
-            self.params, self._caches, piece[None],
-            jnp.asarray(start, jnp.int32), self._rows[slot],
-            jnp.asarray(min(max(last, 0), C - 1), jnp.int32),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(valid, jnp.int32),
-            self._first_token_args(slot, total))
-        self._prefilling[slot][1] = done + 1
-        if start + C < total:
-            return False
-        del self._prefilling[slot]
-        if self.prefix_cache:
-            self.allocator.commit_slot(slot)
-        self._activate_lane(slot, tok[0], total)
+        valid = min(C, total - start)          # real rows in this slice
         act = self.scheduler.active[slot]
-        act.first_token_step = self._now
-        act.tokens.append(int(tok[0]))
+        rid = act.request.rid
+        spans = self._spans
+        spans.chunks.append((rid, start, valid, total))
+        with spans.span("engine.chunk", rid=rid, start=start, valid=valid):
+            piece = item[start:start + C]
+            if valid < C:                      # pad final chunk to C
+                piece = jnp.zeros((C,) + item.shape[1:],
+                                  item.dtype).at[:valid].set(piece)
+            if self._has_window:
+                # slide the ring to cover this slice; rows behind the
+                # slice's FIRST query keep their window (freed only once
+                # fully behind)
+                fresh, freed = self.allocator.extend_window(
+                    slot, min(start + C, total), first_query_pos=start)
+                if fresh or freed:
+                    self._refresh_row(slot, "window")
+            last = total - 1 - start           # only valid on the final chunk
+            tok, self._caches = self._chunk(
+                self.params, self._caches, piece[None],
+                jnp.asarray(start, jnp.int32), self._rows[slot],
+                jnp.asarray(min(max(last, 0), C - 1), jnp.int32),
+                jnp.asarray(slot, jnp.int32), jnp.asarray(valid, jnp.int32),
+                self._first_token_args(slot, total))
+            self._prefilling[slot][1] = done + 1
+            if start + C < total:
+                return False
+            del self._prefilling[slot]
+            if self.prefix_cache:
+                self.allocator.commit_slot(slot)
+            self._activate_lane(slot, tok[0], total)
+            act.first_token_step = self._now
+        # the first token's device -> host sync, apart from the dispatch
+        with spans.span("engine.sync", what="chunk"):
+            act.tokens.append(int(tok[0]))
         return True
 
     def _finish(self, slot: int) -> list:
         """Retire ``slot`` (reclaims blocks and its recurrent state slot;
         paged: unmap its table rows)."""
-        act = self.scheduler.finish(slot)
-        self._samp.pop(slot, None)
-        self._skey_host.pop(slot, None)
-        if self.paged:
-            for group in self._tables:
-                self._tables[group] = self._tables[group].at[slot].set(
-                    self._null_rows[group])
-            self._rows.pop(slot, None)
-            self._host_pos.pop(slot, None)
+        with self._spans.span("engine.finish",
+                              rid=self.scheduler.active[slot].request.rid):
+            act = self.scheduler.finish(slot)
+            self._samp.pop(slot, None)
+            self._skey_host.pop(slot, None)
+            if self.paged:
+                for group in self._tables:
+                    self._tables[group] = self._tables[group].at[slot].set(
+                        self._null_rows[group])
+                self._rows.pop(slot, None)
+                self._host_pos.pop(slot, None)
         return act.tokens
 
     def _grow_tables(self, decoding: list) -> None:
@@ -1236,86 +1250,103 @@ class ContinuousEngine:
             if max_steps is not None and steps >= max_steps:
                 break
             now = self._now
-            t0 = time.perf_counter()
-            prefills = 0                       # completed (one token each)
-            chunks = 0                         # chunk work units
-            for act in self.scheduler.admit(now):
+            with TraceAnnotation("engine.step", step=now):
+                ran = self._step(now, results)
+            if ran is None:
+                break
+            steps += ran
+        return results
+
+    def _step(self, now: int, results: dict) -> Optional[bool]:
+        """One step of ``run()`` at engine clock ``now``: admit, run one
+        chunk per prefilling lane, decode the running lanes, retire the
+        finished ones into ``results``, and record the step.  Each phase
+        is a span of ``self._spans`` (docs/serving.md, "Spans and step
+        records").  Returns True for a recorded step, False when nothing
+        could run and the clock jumped to the next arrival, None when
+        nothing ever will."""
+        spans = self._spans = StepSpans(now)
+        prefills = 0                           # completed (one token each)
+        for act in self.scheduler.admit(now):
+            rid = act.request.rid
+            spans.admitted.append(rid)
+            with spans.span("engine.admit", rid=rid):
                 self._admit_one(act)
-                if act.slot in self._prefilling:
-                    continue                   # chunked: no token yet
-                prefills += 1
-                if act.is_finished():          # max_new == 1 or prompt-EOS
-                    results[act.request.rid] = self._finish(act.slot)
-            # chunked prefills: one chunk per prefilling slot per step,
-            # interleaved with the decode of running lanes below
-            for slot in sorted(self._prefilling):
-                finished = self._run_chunk(slot)
-                chunks += 1
-                if finished:
-                    prefills += 1              # final chunk emitted a token
-                    act = self.scheduler.active[slot]
-                    if act.is_finished():
-                        results[act.request.rid] = self._finish(slot)
+            if act.slot in self._prefilling:
+                continue                       # chunked: no token yet
+            prefills += 1
+            if act.is_finished():              # max_new == 1 or prompt-EOS
+                results[rid] = self._finish(act.slot)
+        # chunked prefills: one chunk per prefilling slot per step,
+        # interleaved with the decode of running lanes below
+        for slot in sorted(self._prefilling):
+            if self._run_chunk(slot):
+                prefills += 1                  # final chunk emitted a token
+                act = self.scheduler.active[slot]
+                if act.is_finished():
+                    results[act.request.rid] = self._finish(slot)
 
-            decoding = sorted(s for s in self.scheduler.active
-                              if s not in self._prefilling)
-            if not decoding:
-                if prefills or chunks:         # all work this step was prefill
-                    self._record_step(now, t0, (), prefills, chunks, 0)
-                    self._now = now + 1
-                    steps += 1
-                    continue
-                nxt = self.scheduler.next_arrival()
-                if nxt is None:
-                    break
-                if nxt <= now and not self.scheduler.active:
-                    # the queue head has arrived, nothing is running that
-                    # could ever free capacity, and admission still refused
-                    # it: the request can never fit.  Fail loudly instead
-                    # of spinning the idle-jump forever.
-                    head = self.scheduler._pending[0]
-                    raise CacheExhausted(
-                        f"request {head.rid!r} (prompt {head.prompt_len} + "
-                        f"max_new {head.max_new_tokens}) can never be "
-                        f"admitted: the empty pool "
-                        f"({self.allocator.n_blocks} blocks) is too small "
-                        f"for its admission price")
-                self._now = max(now + 1, nxt)  # idle: jump to next arrival
-                continue
-
-            if self.paged and self.speculate:
-                # self-speculative decode: one per-lane round per step —
-                # draft, verify in one batched chunk-shaped step, accept,
-                # rewind (growth happens inside the round, per lane)
-                drafted = accepted = rewound = new_tokens = 0
-                ran = []
-                for slot in decoding:
-                    act = self.scheduler.active.get(slot)
-                    if act is None:
-                        continue       # preempted by an earlier round
-                    out = self._speculative_round(slot)
-                    if out is None:
-                        continue       # the lane itself was preempted
-                    ran.append(slot)
-                    emitted, k_r, a = out
-                    drafted += k_r
-                    accepted += a
-                    rewound += k_r - a
-                    for t in emitted:
-                        act.tokens.append(t)
-                        new_tokens += 1
-                        if act.is_finished():
-                            break      # EOS inside the accepted window
-                    if act.is_finished():
-                        results[act.request.rid] = self._finish(slot)
-                self._record_step(now, t0, ran, prefills, chunks,
-                                  new_tokens, drafted=drafted,
-                                  accepted=accepted, rewound=rewound)
+        decoding = sorted(s for s in self.scheduler.active
+                          if s not in self._prefilling)
+        if not decoding:
+            if prefills or spans.chunks:       # all work this step was prefill
+                self._record_step((), prefills, 0)
                 self._now = now + 1
-                steps += 1
-                continue
+                return True
+            nxt = self.scheduler.next_arrival()
+            if nxt is None:
+                return None
+            if nxt <= now and not self.scheduler.active:
+                # the queue head has arrived, nothing is running that
+                # could ever free capacity, and admission still refused
+                # it: the request can never fit.  Fail loudly instead
+                # of spinning the idle-jump forever.
+                head = self.scheduler._pending[0]
+                raise CacheExhausted(
+                    f"request {head.rid!r} (prompt {head.prompt_len} + "
+                    f"max_new {head.max_new_tokens}) can never be "
+                    f"admitted: the empty pool "
+                    f"({self.allocator.n_blocks} blocks) is too small "
+                    f"for its admission price")
+            self._now = max(now + 1, nxt)      # idle: jump to next arrival
+            return False
 
-            if self.paged:
+        if self.paged and self.speculate:
+            # self-speculative decode: one per-lane round per step —
+            # draft, verify in one batched chunk-shaped step, accept,
+            # rewind (growth happens inside the round, per lane)
+            drafted = accepted = rewound = new_tokens = 0
+            ran = []
+            for slot in decoding:
+                act = self.scheduler.active.get(slot)
+                if act is None:
+                    continue           # preempted by an earlier round
+                rid = act.request.rid
+                pos = self._frontend_extra + act.position - 1
+                with spans.span("engine.speculate", rid=rid):
+                    out = self._speculative_round(slot)
+                if out is None:
+                    continue           # the lane itself was preempted
+                ran.append(slot)
+                spans.decoded.append((rid, pos))
+                emitted, k_r, a = out
+                drafted += k_r
+                accepted += a
+                rewound += k_r - a
+                for t in emitted:
+                    act.tokens.append(t)
+                    new_tokens += 1
+                    if act.is_finished():
+                        break          # EOS inside the accepted window
+                if act.is_finished():
+                    results[rid] = self._finish(slot)
+            self._record_step(ran, prefills, new_tokens, drafted=drafted,
+                              accepted=accepted, rewound=rewound)
+            self._now = now + 1
+            return True
+
+        if self.paged:
+            with spans.span("engine.grow_tables"):
                 while True:
                     try:
                         self._grow_tables(decoding)
@@ -1329,11 +1360,12 @@ class ContinuousEngine:
                             raise
                         self._preempt(victim)
                         decoding = [s for s in decoding if s != victim]
-                if not decoding:           # every decoding lane was evicted
-                    self._record_step(now, t0, (), prefills, chunks, 0)
-                    self._now = now + 1
-                    steps += 1
-                    continue
+            if not decoding:               # every decoding lane was evicted
+                self._record_step((), prefills, 0)
+                self._now = now + 1
+                return True
+        with spans.span("engine.decode", lanes=len(decoding)):
+            if self.paged:
                 active = np.zeros((self.n_slots,), bool)
                 active[decoding] = True
                 toks, self._caches = self._decode_p(
@@ -1346,65 +1378,78 @@ class ContinuousEngine:
                     self._skeys, self._temp, self._topk, self._topp)
             self._toks = toks
             self._pos = self._pos + 1
+        with spans.span("engine.sync", what="decode"):
             toks_host = np.asarray(toks)       # one device->host transfer
-            new_tokens = 0
-            for slot in decoding:
-                act = self.scheduler.active.get(slot)
-                if act is None:
-                    continue               # preempted by an earlier lane
-                act.tokens.append(int(toks_host[slot]))
-                new_tokens += 1
-                if self.paged:
-                    self._host_pos[slot] += 1
-                else:
-                    # cache entries resident after this step: prompt + all
-                    # decode writes so far (the just-emitted token is not
-                    # yet written); paged growth happened eagerly above
-                    preempted_self = False
-                    while True:
-                        try:
-                            self.allocator.extend(slot, act.position - 1)
+        new_tokens = 0
+        for slot in decoding:
+            act = self.scheduler.active.get(slot)
+            if act is None:
+                continue               # preempted by an earlier lane
+            spans.decoded.append((act.request.rid,
+                                  self._frontend_extra + act.position - 1))
+            act.tokens.append(int(toks_host[slot]))
+            new_tokens += 1
+            if self.paged:
+                self._host_pos[slot] += 1
+            else:
+                # cache entries resident after this step: prompt + all
+                # decode writes so far (the just-emitted token is not
+                # yet written); paged growth happened eagerly above
+                preempted_self = False
+                while True:
+                    try:
+                        self.allocator.extend(slot, act.position - 1)
+                        break
+                    except CacheExhausted:
+                        victim = self._pick_victim()
+                        if victim is None:
+                            raise
+                        self._preempt(victim)
+                        if victim == slot:
+                            preempted_self = True
                             break
-                        except CacheExhausted:
-                            victim = self._pick_victim()
-                            if victim is None:
-                                raise
-                            self._preempt(victim)
-                            if victim == slot:
-                                preempted_self = True
-                                break
-                    if preempted_self:
-                        new_tokens -= 1    # its token was discarded
-                        continue
-                if act.is_finished():
-                    results[act.request.rid] = self._finish(slot)
-            self._record_step(now, t0, decoding, prefills, chunks, new_tokens)
-            self._now = now + 1
-            steps += 1
-        return results
+                if preempted_self:
+                    new_tokens -= 1    # its token was discarded
+                    spans.decoded.pop()
+                    continue
+            if act.is_finished():
+                results[act.request.rid] = self._finish(slot)
+        self._record_step(decoding, prefills, new_tokens)
+        self._now = now + 1
+        return True
 
-    def _record_step(self, now: int, t0: float, active_slots, prefills: int,
-                     chunks: int, new_tokens: int, drafted: int = 0,
-                     accepted: int = 0, rewound: int = 0) -> None:
-        by_group = self.allocator.resident_bytes_by_group()
-        # per-step deltas of the cumulative ledgers
-        stats = self.allocator.stats
-        cur = (self.scheduler.preemptions, stats["hit_tokens"],
-               stats["lookup_tokens"])
-        prev = self._stats_last
-        self._stats_last = cur
+    def _record_step(self, active_slots, prefills: int, new_tokens: int,
+                     drafted: int = 0, accepted: int = 0,
+                     rewound: int = 0) -> None:
+        """Close the step in progress into a ``ServeStep``.  Host
+        arithmetic only: nothing here runs on the device."""
+        spans = self._spans
+        with spans.span("engine.record"):
+            by_group = self.allocator.resident_bytes_by_group()
+            # per-step deltas of the cumulative ledgers
+            stats = self.allocator.stats
+            cur = (self.scheduler.preemptions, stats["hit_tokens"],
+                   stats["lookup_tokens"])
+            prev = self._stats_last
+            self._stats_last = cur
+            shared = self.allocator.shared_saved_bytes()
+            cached = self.allocator.cached_blocks()
+        t1 = time.perf_counter()
         self.telemetry.record_step(
-            step=now, seconds=time.perf_counter() - t0,
+            step=spans.step, seconds=t1 - spans.t0,
             active_slots=active_slots, n_slots=self.n_slots,
             blocks_in_use=self.allocator.n_in_use,
             n_blocks=self.allocator.n_blocks,
-            prefills=prefills, prefill_chunks=chunks, new_tokens=new_tokens,
+            prefills=prefills, prefill_chunks=len(spans.chunks),
+            new_tokens=new_tokens,
             resident_bytes=sum(by_group.values()),
-            capacity_bytes=self.allocator.capacity_bytes(),
+            capacity_bytes=self._capacity_bytes,
             resident_by_group=by_group if self.paged else None,
             preemptions=cur[0] - prev[0],
             prefix_hit_tokens=cur[1] - prev[1],
             prefix_lookup_tokens=cur[2] - prev[2],
-            shared_saved_bytes=self.allocator.shared_saved_bytes(),
-            cached_blocks=self.allocator.cached_blocks(),
-            drafted=drafted, accepted=accepted, rewound_tokens=rewound)
+            shared_saved_bytes=shared, cached_blocks=cached,
+            drafted=drafted, accepted=accepted, rewound_tokens=rewound,
+            t0=spans.t0, t1=t1, phases=spans.phases,
+            admitted=spans.admitted, chunks=spans.chunks,
+            decoded=spans.decoded)
