@@ -12,8 +12,13 @@ token identity with the static ``Engine`` oracle.
 Shape conventions (see docs/kernels.md):
 
 * ``q``:            [B, Sq, H, hd]   (decode: Sq == 1; chunked prefill: Sq == chunk)
-* ``k_pages/v_pages``: [n_pages, block_size, KV, hd] physical block pool
-  (page ``n_pages - 1`` is conventionally the null/scratch block)
+* ``k_pages/v_pages``: [n_pages, block_size, KV * hd] physical block pool,
+  lane-dense (page ``n_pages - 1`` is conventionally the null/scratch
+  block), or a layer scan's stacked ``[layers, n_pages, block_size,
+  KV * hd]`` pools with ``layer`` picking one.  The ``[KV, hd]`` view is
+  taken after the gather; a row of any shape that flattens to
+  ``KV * hd`` reads the same, such as the Pallas kernel's
+  ``[n_pages, block_size, KV, hd]``
 * ``block_tables``: [B, max_blocks] int32 — logical block i of lane b lives
   in physical page ``block_tables[b, i]``
 * ``context_lens``: [B] int32 — resident tokens per lane, *including* any
@@ -45,15 +50,19 @@ import jax.numpy as jnp
 
 
 def reference(q, k_pages, v_pages, block_tables, context_lens, *,
-              q_positions, logit_softcap=0.0, window=0):
+              q_positions, logit_softcap=0.0, window=0, layer=None):
     """Gather-based paged attention. Returns [B, Sq, H, hd]."""
     B, Sq, H, hd = q.shape
-    n_pages, block_size, n_kv, _ = k_pages.shape
-    L = block_tables.shape[1] * block_size
 
-    # [B, max_blocks, bs, KV, hd] -> [B, L, KV, hd]: logical order 0..L-1
-    k = k_pages[block_tables].reshape(B, L, n_kv, hd)
-    v = v_pages[block_tables].reshape(B, L, n_kv, hd)
+    def gather(pages):
+        # one gather straight from the (stacked) pool: [B, max_blocks, bs,
+        # *row] -> [B, L, KV, hd], logical order 0..L-1
+        g = pages[block_tables] if layer is None else \
+            pages[layer, block_tables]
+        return g.reshape(B, g.shape[1] * g.shape[2], -1, hd)
+
+    k, v = gather(k_pages), gather(v_pages)
+    L, n_kv = k.shape[1], k.shape[2]
     if n_kv != H:
         k = jnp.repeat(k, H // n_kv, axis=2)
         v = jnp.repeat(v, H // n_kv, axis=2)

@@ -205,50 +205,71 @@ def init_attn_cache(cfg: ModelConfig, batch: int, kv_len: int, local: bool,
 def init_paged_attn_cache(cfg: ModelConfig, n_pages: int, block_size: int,
                           dtype) -> dict:
     """Physical block-pool cache for one attention layer: K/V page pools
-    shared by every decode lane, addressed through per-lane block tables
-    (``paged_tables``).  ``n_pages`` includes the trailing null/scratch
-    page inactive lanes write into."""
-    shape = (n_pages, block_size, cfg.n_kv_heads, cfg.head_dim)
+    ``[n_pages, block_size, n_kv_heads * head_dim]`` shared by every decode
+    lane, addressed through per-lane block tables (``paged_tables``).  A
+    row is lane-dense (its heads side by side); the ``[KV, hd]`` view is
+    taken only after the gather, where in row-major order it is a bitcast.
+    ``n_pages`` includes the trailing null/scratch page inactive lanes
+    write into."""
+    shape = (n_pages, block_size, cfg.n_kv_heads * cfg.head_dim)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 
 
 def paged_write(k_pages: jax.Array, v_pages: jax.Array, tables: jax.Array,
-                positions: jax.Array, k: jax.Array, v: jax.Array) -> tuple:
-    """Scatter per-token rows into a pair of page pools through block tables.
+                positions: jax.Array, k: jax.Array, v: jax.Array,
+                layer) -> tuple:
+    """Write per-token rows into layer ``layer`` of a pair of stacked page
+    pools through block tables, in place.
 
-    tables: [B, max_blocks]; positions: [B] (decode: one row per lane) or
-    [S] with B == 1 (chunk prefill: the chunk's rows for one lane);
-    k, v: [B, S, ...] with B == len(positions) or S == len(positions) — the
-    trailing dims are free (attention K/V rows, MLA latent rows).
+    k_pages, v_pages: ``[layers, n_pages, block_size, width]`` — a scan
+    segment's pools, carried whole through its layer scan; ``layer`` (may
+    be traced) picks the layer written.  tables: [B, max_blocks];
+    positions: [B] (decode: one row per lane) or [S] with B == 1 (chunk
+    prefill: the chunk's rows for one lane); k, v: [B, S, ...] with
+    B == len(positions) or S == len(positions), trailing dims flattening
+    to the pool's width (attention K/V rows, MLA latent rows).  Decode
+    writes each lane's row with its own ``dynamic_update_slice`` (B is
+    static); a chunk writes its rows with one scatter.
     Rows whose table entry is the null page land in scratch (inactive lanes,
     padded chunk tails, window-ring entries already freed behind the
     window) — never read back, because reads are masked by ``context_lens``
     (and the window mask for ring layers).
     """
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     width = tables.shape[1]
-    null = k_pages.shape[0] - 1                # scratch page, by convention
+    null = k_pages.shape[1] - 1                # scratch page, by convention
     blk = positions // bs
     safe = jnp.minimum(blk, width - 1)         # in-bounds for the gather only
     off = positions % bs
-    if k.shape[0] == positions.shape[0]:      # decode: one row per lane
+    decode = k.shape[0] == positions.shape[0]  # one row per lane
+    if decode:
         phys = jnp.take_along_axis(tables, safe[:, None], axis=1)[:, 0]
-        rows_k, rows_v = k[:, 0], v[:, 0]
     else:                                      # chunk prefill: B == 1
         phys = tables[0, safe]
-        rows_k, rows_v = k[0], v[0]
     # positions past the table's reach (pad rows of a final chunk, runaway
     # inactive lanes) must go to scratch, not the clamped last real block
     phys = jnp.where(blk < width, phys, null)
-    return k_pages.at[phys, off].set(rows_k), v_pages.at[phys, off].set(rows_v)
+    layer = jnp.asarray(layer, jnp.int32)
+    out = []
+    for pages, rows in ((k_pages, k), (v_pages, v)):
+        rows = rows.reshape(-1, pages.shape[-1]).astype(pages.dtype)
+        if decode:
+            for b in range(rows.shape[0]):
+                pages = lax.dynamic_update_slice(
+                    pages, rows[b].reshape(1, 1, 1, -1),
+                    (layer, phys[b], off[b], jnp.int32(0)))
+        else:
+            pages = pages.at[layer, phys, off].set(rows)
+        out.append(pages)
+    return tuple(out)
 
 
 def _paged_write(cache: dict, tables: jax.Array, positions: jax.Array,
-                 k: jax.Array, v: jax.Array) -> dict:
+                 k: jax.Array, v: jax.Array, layer) -> dict:
     """``paged_write`` over an attention pool leaf ({"k_pages", "v_pages"})."""
     kp, vp = paged_write(cache["k_pages"], cache["v_pages"], tables,
-                         positions, k, v)
+                         positions, k, v, layer)
     return {"k_pages": kp, "v_pages": vp}
 
 
@@ -256,7 +277,8 @@ def attn_layer(cfg: ModelConfig, p: dict, x: jax.Array, *, local: bool,
                positions: jax.Array, cache: Optional[dict] = None,
                kv_override: Optional[tuple] = None, impl: str = "chunked",
                unroll: bool = False, paged_tables: Optional[jax.Array] = None,
-               valid_len=None, shard_fn=None) -> tuple[jax.Array, Optional[dict]]:
+               layer=None, valid_len=None,
+               shard_fn=None) -> tuple[jax.Array, Optional[dict]]:
     """Pre-norm attention block. Returns (residual output, new cache).
 
     Training/prefill: ``positions`` = [S]; decode: x is [B, 1, D] and
@@ -265,15 +287,17 @@ def attn_layer(cfg: ModelConfig, p: dict, x: jax.Array, *, local: bool,
     ``valid_len`` (prefill only): tokens at positions >= valid_len are
     bucket padding — their rows must never displace real cache content.
 
-    Paged mode (cache holds ``k_pages``/``v_pages`` pools and
-    ``paged_tables`` carries [B, max_blocks] block tables): decode is a
-    *batched* step — x is [B, 1, D] and ``positions`` = [B] per-lane
-    absolute positions; prefill is a per-lane *chunk* — x is [1, C, D] and
-    ``positions`` = [C] the chunk's absolute positions.  Both write K/V
-    into the shared pools through the tables, then attend through the
-    gather-based paged kernel.  Local (sliding-window) layers run the same
-    path over their window block ring with the window mask excluding
-    gathered rows behind ``q_pos - window`` (see docs/serving.md).
+    Paged mode (cache holds the segment's stacked ``k_pages``/``v_pages``
+    pools, ``layer`` this layer's index into them, and ``paged_tables``
+    carries [B, max_blocks] block tables): decode is a *batched* step — x
+    is [B, 1, D] and ``positions`` = [B] per-lane absolute positions;
+    prefill is a per-lane *chunk* — x is [1, C, D] and ``positions`` = [C]
+    the chunk's absolute positions.  Both write K/V into the shared pools
+    through the tables, in place, then attend through the gather-based
+    paged kernel, which gathers this layer's pages from the stack.  Local
+    (sliding-window) layers run the same path over their window block
+    ring with the window mask excluding gathered rows behind
+    ``q_pos - window`` (see docs/serving.md).
     """
     B, S, _ = x.shape
     window = cfg.window_size if local else 0
@@ -294,31 +318,34 @@ def attn_layer(cfg: ModelConfig, p: dict, x: jax.Array, *, local: bool,
 
     if cache is not None and "k_pages" in cache:  # physical paged cache
         assert paged_tables is not None, "paged cache needs block tables"
+        assert layer is not None, "paged cache needs the layer's index"
         if S == 1:  # batched decode: one token per lane, per-lane positions
             pos = positions.reshape(-1)                       # [B]
             q = apply_rope(q, pos[:, None], cfg.rope_theta)
             k = apply_rope(k, pos[:, None], cfg.rope_theta)
-            new_cache = _paged_write(cache, paged_tables, pos, k, v)
             ctx = pos + 1                  # resident incl. the token just written
             q_pos = pos[:, None]
         else:       # chunk prefill: B == 1 lane, S == chunk rows
             pos = positions.reshape(-1)                       # [S]
             q = apply_rope(q, pos, cfg.rope_theta)
             k = apply_rope(k, pos, cfg.rope_theta)
-            new_cache = _paged_write(cache, paged_tables, pos, k, v)
             ctx = pos[-1][None] + 1
             q_pos = pos[None]
+        new_cache = _paged_write(cache, paged_tables, pos, k, v, layer)
         if impl == "pallas" and S == 1:
             from repro.kernels.paged_attention import ops as pa_ops
+            kp, vp = (lax.dynamic_index_in_dim(
+                new_cache[n], layer, keepdims=False).reshape(
+                    -1, new_cache[n].shape[2], cfg.n_kv_heads, cfg.head_dim)
+                for n in ("k_pages", "v_pages"))
             o = pa_ops.paged_attention(
-                q[:, 0], new_cache["k_pages"], new_cache["v_pages"],
-                paged_tables, ctx,
+                q[:, 0], kp, vp, paged_tables, ctx,
                 logit_softcap=cfg.attn_logit_softcap, window=window)[:, None]
         else:
             from repro.kernels.paged_attention import ref as pa_ref
             o = pa_ref.reference(
                 q, new_cache["k_pages"], new_cache["v_pages"], paged_tables,
-                ctx, q_positions=q_pos,
+                ctx, q_positions=q_pos, layer=layer,
                 logit_softcap=cfg.attn_logit_softcap, window=window)
         out = sf(o, "heads").reshape(B, S, cfg.q_dim) @ p["wo"]
         return x + out, new_cache

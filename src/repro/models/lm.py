@@ -254,8 +254,9 @@ def init_paged_caches(cfg: ModelConfig, n_slots: int, n_pages: int,
     """Paged decode cache tree with mixed layer groups, stacked to
     ``[repeats, ...]`` to mirror the scan segments like ``init_cache``:
 
-    * global attention — shared ``[n_pages, block_size, KV, hd]`` K/V page
-      pools (no slot axis — lanes are carved out by block tables);
+    * global attention — shared ``[n_pages, block_size, KV * hd]`` K/V
+      page pools, lane-dense (no slot axis — lanes are carved out by block
+      tables);
     * MLA — shared latent page pools (ckv/krope rows), same block tables;
     * sliding-window attention — the same pool shape, addressed through
       window ring tables (entries behind the window are null);
@@ -264,6 +265,10 @@ def init_paged_caches(cfg: ModelConfig, n_slots: int, n_pages: int,
     * enc-dec cross attention — every decoder layer additionally carries
       an ``xattn`` K/V page pool, addressed through per-slot *static*
       cross tables (written once at admission, never extended).
+
+    The stacked pools ride whole in the carry of the segment's layer scan
+    (``_run_segment``), so a jitted step that donates the tree updates
+    them in place.
     """
     def stack(leaf: dict, repeats: int) -> dict:
         return jax.tree.map(
@@ -453,18 +458,19 @@ def _scatter_rows(pages, row_tbl, cpos, rows, *, block_size: int,
                   null_block: int):
     """Write per-position rows into a page pool through one table row.
 
-    ``pages``: [repeats, n_pages, block_size, *row]; ``row_tbl``: [W] the
+    ``pages``: [repeats, n_pages, block_size, width]; ``row_tbl``: [W] the
     lane's physical blocks; ``cpos``: [S] absolute cache positions (-1 =
-    invalid); ``rows``: [repeats, S, *row].  Rows whose position is -1 or
-    whose block is not covered by the table are redirected to the null
-    page."""
+    invalid); ``rows``: [repeats, S, *row], a row flattening to ``width``
+    (dense K/V rows are [KV, hd]).  Rows whose position is -1 or whose
+    block is not covered by the table are redirected to the null page."""
     width = row_tbl.shape[0]
     blk = jnp.clip(jnp.where(cpos >= 0, cpos // block_size, 0),
                    0, width - 1)
     ok = (cpos >= 0) & ((cpos // block_size) < width)
     phys = jnp.where(ok, row_tbl[blk], null_block)
     off = jnp.where(cpos >= 0, cpos % block_size, 0)
-    return pages.at[:, phys, off].set(rows)
+    return pages.at[:, phys, off].set(
+        rows.reshape(rows.shape[:2] + pages.shape[3:]))
 
 
 def insert_paged_prompt(cfg: ModelConfig, caches: dict, single: dict,
@@ -652,8 +658,9 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
                  n_groups: int, capacity_factor: float = 1.25,
                  moe_lossless: bool = False, unroll: bool = False,
                  paged_tables=None, window_tables=None, cross_tables=None,
-                 valid_len=None, shard_fn=None):
-    """One layer. Returns (h, new_cache_or_None, aux)."""
+                 layer=None, valid_len=None, shard_fn=None):
+    """One layer. Returns (h, new_cache_or_None, aux).  ``layer`` is the
+    layer's index into its segment's stacked page pools (paged mode)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
 
@@ -665,14 +672,16 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
                                  impl=impl, unroll=unroll,
                                  paged_tables=(window_tables if local
                                                else paged_tables),
-                                 valid_len=valid_len, shard_fn=shard_fn)
+                                 layer=layer, valid_len=valid_len,
+                                 shard_fn=shard_fn)
         if c is not None:
             new_cache["attn"] = c
     elif spec.mixer == "mla":
         h, c = mla_mod.mla_layer(cfg, p["mla"], h, positions=positions,
                                  cache=cache.get("mla") if cache else None,
                                  impl=impl, unroll=unroll,
-                                 paged_tables=paged_tables, shard_fn=shard_fn)
+                                 paged_tables=paged_tables, layer=layer,
+                                 shard_fn=shard_fn)
         if c is not None:
             new_cache["mla"] = c
     elif spec.mixer == "ssd":
@@ -700,11 +709,11 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
             # oracle's F-row cross attention bitwise.
             assert cross_tables is not None, "paged cross KV needs tables"
             kp, vp = xc["k_pages"], xc["v_pages"]
-            bs = kp.shape[1]
             B_l = cross_tables.shape[0]
-            Lc = cross_tables.shape[1] * bs
-            xk = kp[cross_tables].reshape((B_l, Lc) + kp.shape[2:])
-            xv = vp[cross_tables].reshape((B_l, Lc) + vp.shape[2:])
+            Lc = cross_tables.shape[1] * kp.shape[2]
+            kv_row = (B_l, Lc, cfg.n_kv_heads, cfg.head_dim)
+            xk = kp[layer, cross_tables].reshape(kv_row)
+            xv = vp[layer, cross_tables].reshape(kv_row)
             j = jnp.arange(Lc, dtype=jnp.int32)
             k_pos = jnp.where(j < F, j, -1)
             new_cache["xattn"] = xc
@@ -736,20 +745,53 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
     return h, (new_cache if new_cache else None), aux
 
 
+def _pool_keys(entry: dict) -> tuple:
+    """Keys of a layer's cache entry that hold page pools (attention K/V,
+    MLA latents, cross K/V); recurrent state and dense caches hold none."""
+    return tuple(k for k, leaf in entry.items()
+                 if "k_pages" in leaf or "ckv_pages" in leaf)
+
+
 def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                  positions, seg_cache, enc_out, impl: str, n_groups: int,
-                 remat: bool, capacity_factor: float = 1.25,
+                 remat: bool, repeats: Optional[int] = None,
+                 capacity_factor: float = 1.25,
                  moe_lossless: bool = False, unroll: bool = False,
                  paged_tables=None, window_tables=None, cross_tables=None,
                  valid_len=None, shard_fn=None):
+    """Scan the segment's layers — its first ``repeats`` cycle repeats
+    when given (the truncated draft pass; the rest keep their cache).
+    Returns (h, new segment cache or None, summed aux).
+
+    Page pools ride in the scan's *carry* as the whole stacked
+    ``[seg.repeats, n_pages, ...]`` arrays: each layer writes its rows
+    into, and gathers its pages from, its own index of the stack, so a
+    step that donates the pools updates them in place instead of
+    rebuilding a layer's pool per iteration.  Layer weights and every
+    other cache leaf (recurrent state, dense caches) stay in xs/ys."""
+    run = seg.repeats if repeats is None else repeats
+    names = [f"c{ci}" for ci in range(len(seg.cycle))]
+    pools: dict = {c: {} for c in names}
+    rest = None
+    if seg_cache is not None:
+        pools = {c: {k: seg_cache[c][k] for k in _pool_keys(seg_cache[c])}
+                 for c in names}
+        rest = {c: {k: v for k, v in seg_cache[c].items()
+                    if k not in pools[c]} for c in names}
+    if run < seg.repeats:
+        head = lambda t: jax.tree.map(lambda x: x[:run], t)
+        seg_p, xs_rest = head(seg_p), head(rest)
+    else:
+        xs_rest = rest
+
     def body(carry, xs):
-        hh = carry
-        ps, cs = xs
+        hh, pl = carry
+        ps, cs, layer = xs
         new_cs: dict = {}
         aux = jnp.zeros((), jnp.float32)
-        for ci, spec in enumerate(seg.cycle):
-            lc = cs[f"c{ci}"] if cs is not None else None
-            hh, nc, a = _apply_layer(cfg, spec, ps[f"c{ci}"], hh,
+        for c, spec in zip(names, seg.cycle):
+            lc = None if cs is None else {**cs[c], **pl[c]}
+            hh, nc, a = _apply_layer(cfg, spec, ps[c], hh,
                                      positions=positions, cache=lc,
                                      enc_out=enc_out, impl=impl,
                                      n_groups=n_groups,
@@ -759,18 +801,26 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                                      paged_tables=paged_tables,
                                      window_tables=window_tables,
                                      cross_tables=cross_tables,
-                                     valid_len=valid_len,
+                                     layer=layer, valid_len=valid_len,
                                      shard_fn=shard_fn)
             aux = aux + a
             if nc is not None:
-                new_cs[f"c{ci}"] = nc
-        return hh, (new_cs if new_cs else None, aux)
+                pl = {**pl, c: {k: nc[k] for k in pl[c]}}
+                new_cs[c] = {k: v for k, v in nc.items() if k not in pl[c]}
+        return (hh, pl), (new_cs if new_cs else None, aux)
 
     if remat:
         body = jax.checkpoint(body)
-    h, (new_caches, auxs) = lax.scan(body, h, (seg_p, seg_cache),
-                                     unroll=seg.repeats if unroll else 1)
-    return h, new_caches, jnp.sum(auxs)
+    (h, pools), (new_rest, auxs) = lax.scan(
+        body, (h, pools), (seg_p, xs_rest, jnp.arange(run, dtype=jnp.int32)),
+        unroll=run if unroll else 1)
+    if new_rest is None:
+        return h, None, jnp.sum(auxs)
+    if run < seg.repeats:  # splice the run repeats over the untouched tail
+        new_rest = jax.tree.map(
+            lambda full, part: jnp.concatenate([part, full[run:]], axis=0),
+            rest, new_rest)
+    return h, {c: {**new_rest[c], **pools[c]} for c in names}, jnp.sum(auxs)
 
 
 def _encode(cfg: ModelConfig, params: dict, frontend_emb, *,
@@ -893,45 +943,20 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
     remaining = None if layer_cap is None else max(int(layer_cap), 1)
     for si, seg in enumerate(cfg.segments()):
         seg_cache = cache[f"seg{si}"] if cache is not None else None
-        seg_p = params[f"seg{si}"]
-        run = seg
+        run = seg.repeats
         if remaining is not None:
             clen = len(seg.cycle)
-            r = min(seg.repeats, -(-remaining // clen)) if remaining > 0 else 0
-            remaining -= r * clen
-            if r == 0:  # cap reached: pass the cache through untouched
+            run = min(seg.repeats, -(-remaining // clen)) if remaining > 0 else 0
+            remaining -= run * clen
+            if run == 0:  # cap reached: pass the cache through untouched
                 if seg_cache is not None:
                     new_cache[f"seg{si}"] = seg_cache
                 continue
-            if r < seg.repeats:  # partial segment: run the first r repeats
-                take = lambda t: jax.tree.map(lambda x: x[:r], t)
-                run = Segment(seg.cycle, r)
-                seg_p = take(seg_p)
-                sub_cache = take(seg_cache) if seg_cache is not None else None
-                h, ncs, aux = _run_segment(
-                    cfg, run, seg_p, h, positions=positions,
-                    seg_cache=sub_cache, enc_out=enc_out, impl=impl,
-                    n_groups=n_groups, remat=remat,
-                    capacity_factor=capacity_factor,
-                    moe_lossless=moe_lossless, unroll=unroll,
-                    paged_tables=paged_tables, window_tables=window_tables,
-                    cross_tables=cross_tables, valid_len=valid_len,
-                    shard_fn=shard_fn)
-                h = shard_fn(h, "residual")
-                aux_total = aux_total + aux
-                if ncs is not None and seg_cache is not None:
-                    # splice the partial segment's cache back over the
-                    # untouched tail repeats
-                    new_cache[f"seg{si}"] = jax.tree.map(
-                        lambda full, part: jnp.concatenate(
-                            [part, full[r:]], axis=0), seg_cache, ncs)
-                elif ncs is not None:
-                    new_cache[f"seg{si}"] = ncs
-                continue
         h, ncs, aux = _run_segment(
-            cfg, run, seg_p, h, positions=positions,
+            cfg, seg, params[f"seg{si}"], h, positions=positions,
             seg_cache=seg_cache, enc_out=enc_out, impl=impl,
-            n_groups=n_groups, remat=remat, capacity_factor=capacity_factor,
+            n_groups=n_groups, remat=remat, repeats=run,
+            capacity_factor=capacity_factor,
             moe_lossless=moe_lossless, unroll=unroll,
             paged_tables=paged_tables, window_tables=window_tables,
             cross_tables=cross_tables, valid_len=valid_len,

@@ -71,7 +71,7 @@ def _project(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
 def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
               positions: jax.Array, cache: Optional[dict] = None,
               impl: str = "chunked", unroll: bool = False,
-              paged_tables: Optional[jax.Array] = None,
+              paged_tables: Optional[jax.Array] = None, layer=None,
               shard_fn=None) -> tuple[jax.Array, Optional[dict]]:
     B, S, D = x.shape
     nh = cfg.n_heads
@@ -79,7 +79,9 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
 
     if cache is not None and "ckv_pages" in cache:  # physical paged latents
         assert paged_tables is not None, "paged MLA cache needs block tables"
-        return _mla_paged(cfg, p, x, h, positions, cache, paged_tables)
+        assert layer is not None, "paged MLA cache needs the layer's index"
+        return _mla_paged(cfg, p, x, h, positions, cache, paged_tables,
+                          layer)
 
     if cache is not None and S == 1:
         return _mla_decode(cfg, p, x, h, positions, cache)
@@ -107,8 +109,12 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
     return x + out, new_cache
 
 
-def _mla_paged(cfg, p, x, h, positions, cache, tables):
+def _mla_paged(cfg, p, x, h, positions, cache, tables, layer):
     """Absorbed attention over block-table-paged latents.
+
+    ``cache`` holds the segment's stacked ``[layers, n_pages, block_size,
+    *]`` latent pools, carried through the layer scan; this layer writes
+    its rows into, and gathers its pages from, index ``layer``.
 
     Two shapes, mirroring the paged attention layer: batched decode (x is
     [B, 1, D], ``positions`` = [B] per-lane absolute positions) and chunked
@@ -134,12 +140,13 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables):
         ctx = pos[-1][None] + 1
         q_pos = pos[None]                                        # [1, S]
     ckv_pages, krope_pages = paged_write(
-        cache["ckv_pages"], cache["krope_pages"], tables, pos, ckv_t, krope_t)
+        cache["ckv_pages"], cache["krope_pages"], tables, pos, ckv_t, krope_t,
+        layer)
 
-    bs = ckv_pages.shape[1]
+    bs = ckv_pages.shape[2]
     L = tables.shape[1] * bs
-    ckv_c = ckv_pages[tables].reshape(B, L, cfg.kv_lora_rank)
-    krope_c = krope_pages[tables].reshape(B, L, cfg.qk_rope_dim)
+    ckv_c = ckv_pages[layer, tables].reshape(B, L, cfg.kv_lora_rank)
+    krope_c = krope_pages[layer, tables].reshape(B, L, cfg.qk_rope_dim)
     j = jnp.arange(L, dtype=jnp.int32)
     pos_c = jnp.where(j[None] < ctx[:, None], j[None], -1)       # [B, L]
 

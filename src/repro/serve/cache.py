@@ -65,7 +65,7 @@ Two layers:
 * ``BlockAllocator`` — pure host bookkeeping (free list + per-slot group
   tables); runs between device steps, no jax in the hot path.
 * ``PagedKVStore`` — a pair of physical page pools of shape
-  ``[n_layers, n_blocks + 1, block_size, *row]`` the tables index into
+  ``[n_layers, n_blocks + 1, block_size, width]`` the tables index into
   (the extra trailing page is the *null block*: inactive decode lanes,
   padded table tails, and freed-behind-window ring entries point at it, so
   their writes land harmlessly and their reads are masked).  Attention
@@ -163,9 +163,9 @@ class PagedKVStore:
     """Physical paged storage for a stack of layers of one cache group.
 
     Owns a pair of page pools ``k_pages``/``v_pages`` of shape
-    ``[n_layers, n_blocks + 1, block_size, *row]`` — attention leaves pair
-    K/V rows (``row = (n_kv_heads, head_dim)``), MLA leaves pair
-    ckv/krope latent rows (the two pools may have different row widths).
+    ``[n_layers, n_blocks + 1, block_size, width]`` — attention leaves pair
+    lane-dense K/V rows (``width = n_kv_heads * head_dim``), MLA leaves
+    pair ckv/krope latent rows (the two pools may have different widths).
     Page ``n_blocks`` is the null block (see module docstring).  All
     updates are functional — methods replace ``self.k_pages``/``self.v_pages``
     with the updated arrays, so a store can also be *rebound* to pool
@@ -178,7 +178,7 @@ class PagedKVStore:
         import jax.numpy as jnp
         dtype = dtype or jnp.float32
         shape = (n_layers, config.n_blocks + 1, config.block_size,
-                 n_kv_heads, head_dim)
+                 n_kv_heads * head_dim)
         self.config = config
         self.k_pages = jnp.zeros(shape, dtype)
         self.v_pages = jnp.zeros(shape, dtype)
@@ -218,23 +218,25 @@ class PagedKVStore:
 
     # -- physical access ---------------------------------------------------------
     def write_token(self, table: list, pos: int, k, v) -> None:
-        """Write one token's rows (``[n_layers, *row]``) at logical
-        position ``pos`` of the lane backed by ``table``."""
+        """Write one token's rows (``[n_layers, *row]``, each row
+        flattening to its pool's width — ``[n_layers, KV, hd]`` for K/V) at
+        logical position ``pos`` of the lane backed by ``table``."""
         block = table[pos // self.config.block_size]
         off = pos % self.config.block_size
-        self.k_pages = self.k_pages.at[:, block, off].set(k)
-        self.v_pages = self.v_pages.at[:, block, off].set(v)
+        L = self.n_layers
+        self.k_pages = self.k_pages.at[:, block, off].set(k.reshape(L, -1))
+        self.v_pages = self.v_pages.at[:, block, off].set(v.reshape(L, -1))
 
     def gather_slot(self, table: list, context_len: int):
         """Reconstruct the lane's logical rows: ``[n_layers, context_len,
-        *row]`` each, gathered through ``table``."""
+        width]`` each, gathered through ``table``."""
         import jax.numpy as jnp
         idx = jnp.asarray(table, jnp.int32)
         L = self.n_layers
         k = self.k_pages[:, idx].reshape(
-            (L, -1) + self.k_pages.shape[3:])[:, :context_len]
+            L, -1, self.k_pages.shape[3])[:, :context_len]
         v = self.v_pages[:, idx].reshape(
-            (L, -1) + self.v_pages.shape[3:])[:, :context_len]
+            L, -1, self.v_pages.shape[3])[:, :context_len]
         return k, v
 
 

@@ -32,7 +32,10 @@ per-step telemetry (slot occupancy, cache pressure, latency) feeds the paper
   projected rows prepend the decoder sequence and page through the normal
   self-attention tables.  Decode is one batched step that writes each
   lane's token through its group tables and attends via the gather-based
-  paged kernel (window-masked for ring layers).  For all-global archs the
+  paged kernel (window-masked for ring layers).  The pools are lane-dense
+  (``[.., block_size, n_kv_heads * head_dim]``), ride whole in the layer
+  scan's carry, and every jitted step that returns them takes them
+  donated, so a step updates them in place.  For all-global archs the
   gathered view has exactly ``kv_len`` (+ frontend) rows
   (``% block_size == 0`` is enforced) and masked rows contribute exact
   zeros, so tokens are bit-identical to the oracle; window/recurrent
@@ -74,6 +77,23 @@ from .sampling import GREEDY, SamplingParams
 from .scheduler import ActiveSlot, Request, SlotScheduler
 
 PREFILL_BUCKET_FLOOR = 8
+
+
+def _donates() -> bool:
+    """Whether the jitted steps take the cache tree donated: on an
+    accelerator, where the page pools fill device memory.  The host (CPU)
+    backend keeps each step's input alive instead — memory there is not
+    the constraint, and a caller that wraps a step may hand its input tree
+    back as the step's result."""
+    return jax.default_backend() != "cpu"
+
+
+def _jit_donating(fn, argnum: int):
+    """``jax.jit(fn)`` with the cache tree at ``argnum`` donated (see
+    ``_donates``): the step's output pools alias its input pools, so no
+    second copy of them is ever live.  The caller must drop every
+    reference to the tree it passed in."""
+    return jax.jit(fn, donate_argnums=(argnum,) if _donates() else ())
 
 
 def bucket_length(n: int, cap: int, floor: int = PREFILL_BUCKET_FLOOR) -> int:
@@ -666,11 +686,15 @@ class ContinuousEngine:
         self._rows: dict[int, dict[str, jax.Array]] = {}
         self._host_pos: dict[int, int] = {}
 
-        self._decode_p = jax.jit(make_paged_decode_step(self.cfg, self.impl))
+        # every jitted function that returns the pool tree takes it donated
+        # (``_jit_donating``); the ``_caches`` setter rebinds the stores to
+        # the returned pools, so nothing keeps a donated one
+        self._decode_p = _jit_donating(
+            make_paged_decode_step(self.cfg, self.impl), 1)
         if self.prefill_chunk:
-            self._chunk = jax.jit(make_chunk_prefill_step(
+            self._chunk = _jit_donating(make_chunk_prefill_step(
                 self.cfg, self.prefill_chunk, self.impl,
-                embeds=bool(self._frontend_extra)))
+                embeds=bool(self._frontend_extra)), 1)
 
         def paged_insert(caches, single, rows, slot, skip):
             return lm.insert_paged_prompt(
@@ -684,18 +708,18 @@ class ContinuousEngine:
             def copy_block(caches, src, dst):
                 return lm.copy_paged_block(self.cfg, caches, src, dst)
 
-            self._copy_block = jax.jit(copy_block)
+            self._copy_block = _jit_donating(copy_block, 0)
 
         def reset_state(caches, single, slot):
             return lm.write_state_lanes(self.cfg, caches, single, slot)
 
-        self._reset_state = jax.jit(reset_state)
+        self._reset_state = _jit_donating(reset_state, 0)
 
         if self.speculate:
-            self._draft_step = jax.jit(make_draft_decode_step(
-                self.cfg, self.draft_layers, self.impl))
-            self._verify_step = jax.jit(make_verify_step(
-                self.cfg, self.speculate + 1, self.impl))
+            self._draft_step = _jit_donating(make_draft_decode_step(
+                self.cfg, self.draft_layers, self.impl), 1)
+            self._verify_step = _jit_donating(make_verify_step(
+                self.cfg, self.speculate + 1, self.impl), 1)
             self._accept = jax.jit(sampling_mod.speculative_accept)
             if self._has_state:
                 def snapshot(caches, slot):
@@ -706,7 +730,7 @@ class ContinuousEngine:
                                                   slot)
 
                 self._snapshot = jax.jit(snapshot)
-                self._restore = jax.jit(restore)
+                self._restore = _jit_donating(restore, 0)
 
         if self._has_cross:
             # encode-at-admission for the chunked path: the encoder runs
@@ -722,14 +746,14 @@ class ContinuousEngine:
                     block_size=self.block_size, null_block=null)
 
             self._encode_cross = jax.jit(encode_cross)
-            self._insert_cross = jax.jit(insert_cross)
+            self._insert_cross = _jit_donating(insert_cross, 0)
 
         def lane_set(toks, pos, tables, slot, tok, start_pos, rows):
             tables = {g: tables[g].at[slot].set(rows[g]) for g in tables}
             return (toks.at[slot].set(tok), pos.at[slot].set(start_pos),
                     tables)
 
-        self._insert_p = jax.jit(paged_insert)
+        self._insert_p = _jit_donating(paged_insert, 0)
         self._lane_set = jax.jit(lane_set)
 
     @functools.cached_property
@@ -748,9 +772,9 @@ class ContinuousEngine:
 
     @_caches.setter
     def _caches(self, tree: dict) -> None:
-        # every jitted step returns fresh pools: the allocator's stores must
-        # follow at once, or they pin the superseded version and keep a
-        # second full copy of the pools alive on the device
+        # a jitted step consumes the pools it was given (donated) and
+        # returns them updated: the allocator's stores must follow at once,
+        # or they hold deleted arrays (or, undonated, pin a second copy)
         self._cache_tree = tree
         self._rebind_stores()
 
@@ -810,11 +834,12 @@ class ContinuousEngine:
         leaves = [(keys, leaf) for group, keys, leaf in
                   lm.paged_cache_leaves(self.cfg, self._caches)
                   if group == "global"]
-        for h, block in pairs:
-            payload = by_hash[h]
-            for (keys, leaf), (k_page, v_page) in zip(leaves, payload):
-                leaf[keys[0]] = leaf[keys[0]].at[:, block].set(k_page)
-                leaf[keys[1]] = leaf[keys[1]].at[:, block].set(v_page)
+        # one scatter per pool: each update rewrites the whole pool
+        blocks = jnp.asarray([block for _, block in pairs], jnp.int32)
+        for i, (keys, leaf) in enumerate(leaves):
+            for j, key in enumerate(keys):
+                pages = jnp.stack([by_hash[h][i][j] for h, _ in pairs], axis=1)
+                leaf[key] = leaf[key].at[:, blocks].set(pages)
         self._rebind_stores()
         return len(pairs)
 

@@ -30,11 +30,11 @@ def test_store_write_gather_roundtrip_across_block_boundary():
     alloc.extend(0, 6)                               # crosses into block 2
     for pos in range(3, 6):
         alloc.write_token(0, pos, krows[pos], vrows[pos])
-    k, v = alloc.gather_slot(0)                      # [L, 6, KV, hd]
-    assert k.shape == (2, 6, 2, 8)
+    k, v = alloc.gather_slot(0)                      # [L, 6, KV * hd]
+    assert k.shape == (2, 6, 16)
     for pos in range(6):
-        assert jnp.all(k[:, pos] == krows[pos]), pos
-        assert jnp.all(v[:, pos] == vrows[pos]), pos
+        assert jnp.all(k[:, pos] == krows[pos].reshape(2, 16)), pos
+        assert jnp.all(v[:, pos] == vrows[pos].reshape(2, 16)), pos
     alloc.free_slot(0)
     alloc.check_no_leaks()
 

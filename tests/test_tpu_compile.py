@@ -13,6 +13,7 @@ file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,3 +97,70 @@ def test_kernel_compiles_for_v5e(one_chip, name):
                         **{k: sds(v) for k, v in kwargs.items()},
                         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# -- the engine's paged steps: pools updated in place ------------------------
+
+def _pool_ops(hlo: str, n_pages: int) -> list[str]:
+    """HLO ``copy``, ``copy-start`` and ``convert`` instructions whose
+    shape has ``n_pages`` (a page pool's page count) as a dimension."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = (.+?) (copy|copy-start|convert)\(",
+                     line)
+        if m and any(str(n_pages) in dims.split(",")
+                     for dims in re.findall(r"\[([\d,]*)\]", m.group(1))):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.fixture(scope="module")
+def paged_steps(one_chip):
+    """minicpm-2b at published widths in bf16, the depth cut to 4 layers,
+    served as the benchmark serves it (4 lanes x 2048 positions, blocks of
+    16, chunks of 256): the engine's own decode and chunk programs, built
+    as on a chip (cache tree donated), compiled for one described v5e."""
+    import dataclasses
+
+    from repro.configs import get
+    from repro.models import lm
+    from repro.serve import engine as engine_mod
+
+    cfg = dataclasses.replace(get("minicpm-2b"), n_layers=4)
+    donates = engine_mod._donates
+    engine_mod._donates = lambda: True
+    try:
+        eng = engine_mod.ContinuousEngine(
+            cfg, None, kv_len=2048, n_slots=4, dtype=jnp.bfloat16,
+            paged=True, prefill_chunk=256, block_size=16)
+    finally:
+        engine_mod._donates = donates
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    tree = lambda t: jax.tree.map(sds, t)
+    params = tree(jax.eval_shape(lambda: lm.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    caches = tree(eng._caches)
+    i32 = lambda *shape: sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+    lanes = tree((eng._skeys, eng._temp, eng._topk, eng._topp))
+    decode = eng._decode_p.lower(
+        params, caches, tree(eng._toks), tree(eng._pos), tree(eng._tables),
+        sds(jax.ShapeDtypeStruct((4,), jnp.bool_)), lanes).compile()
+    first = tree((eng._skeys[0], eng._temp[0], eng._topk[0], eng._topp[0]))
+    chunk = eng._chunk.lower(
+        params, caches, i32(1, 256), i32(), {"global": i32(128)}, i32(),
+        i32(), i32(), first).compile()
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(eng._caches))
+    n_pages = eng.allocator.config.n_blocks + 1
+    return {"decode": decode, "chunk": chunk}, pool_bytes, n_pages
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_paged_step_updates_the_pools_in_place(paged_steps, step):
+    programs, pool_bytes, n_pages = paged_steps
+    compiled = programs[step]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        mem.alias_size_in_bytes, pool_bytes)
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    assert _pool_ops(compiled.as_text(), n_pages) == []
