@@ -2,13 +2,16 @@
 
 27L d_model=2048 16H, MLA kv_lora=512, MoE 64 routed experts top-6 + 2 shared,
 expert d_ff=1408, vocab=102400. First layer uses a dense FFN (d_ff=10944).
+Softmax gates are not renormalised over the top 6 (``norm_topk_prob``
+false); the MLA rope is YaRN-scaled (factor 40 over 4096 original
+positions), which also scales the attention logits by mscale^2.
 
 Note: the assignment line reads "2 shared+160 routed top-6"; 160 routed is the
 *full* V2 config — V2-**Lite** (this arch id, and the same line's "MoE 64e
 top-6") has 64 routed experts. We follow 64 (documented in DESIGN.md).
 """
 
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, Yarn
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -27,6 +30,7 @@ CONFIG = ModelConfig(
     n_shared_experts=2,
     d_ff_expert=1408,
     router_aux_coef=0.003,
+    norm_topk_prob=False,
     # MLA dims (V2-Lite: no q compression)
     q_lora_rank=0,
     kv_lora_rank=512,
@@ -35,4 +39,6 @@ CONFIG = ModelConfig(
     v_head_dim=128,
     ffn_act="silu",
     rope_theta=10_000.0,
+    rope_yarn=Yarn(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                   beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707),
 )

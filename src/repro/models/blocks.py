@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .config import ModelConfig
+from .config import ModelConfig, Yarn, yarn_mscale
 
 DEFAULT_CHUNK = 1024
 
@@ -73,19 +73,49 @@ rms_norm.defvjp(lambda x, scale, eps: _rms_fwd(x, scale, eps),
                 _rms_bwd)
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float,
+               yarn: Optional[Yarn] = None) -> jax.Array:
+    """Inverse rotary frequencies [head_dim / 2], computed at trace time.
+    ``yarn``: DeepSeek-V2's YaRN table — pairs rotating fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, pairs rotating more than ``beta_fast`` times are kept, and
+    a linear ramp blends the correction range between."""
+    extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn is None:
+        return extra
+
+    def dim(rotations: float) -> float:
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), head_dim - 1)
+    if high == low:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, hd]; positions: [S] or [B, S] absolute token positions."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[Yarn] = None) -> jax.Array:
+    """x: [B, S, H, hd]; positions: [S] or [B, S] absolute token positions.
+    ``yarn`` scales the frequencies (``rope_freqs``) and cos/sin by the
+    ratio of its two mscales (1 for DeepSeek-V2, whose two are equal)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # [hd/2]
+    freqs = rope_freqs(hd, theta, yarn)                 # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     if angles.ndim == 2:                                # [S, hd/2] -> broadcast B
         angles = angles[None]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -125,12 +155,15 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               q_positions: jax.Array, k_positions: jax.Array,
               causal: bool = True, window: int = 0,
               logit_softcap: float = 0.0, impl: str = "chunked",
-              chunk: int = DEFAULT_CHUNK, unroll: bool = False) -> jax.Array:
+              chunk: int = DEFAULT_CHUNK, unroll: bool = False,
+              scale: Optional[float] = None) -> jax.Array:
     """Softmax attention with GQA, optional sliding window and logit softcap.
 
     q: [B, Sq, H, hd]; k, v: [B, Skv, KV, hd]. Positions are absolute.
+    ``scale`` multiplies the logits (default ``hd ** -0.5``).
     """
     if impl == "pallas":
+        assert scale is None, "the flash kernel scales by hd ** -0.5"
         from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(
             q, k, v, q_positions=q_positions, k_positions=k_positions,
@@ -139,7 +172,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     n_heads = q.shape[2]
     k = _expand_kv(k, n_heads)
     v = _expand_kv(v, n_heads)
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
 
     if impl == "naive" or q.shape[1] <= chunk:
         return _attn_block(q, k, v, q_positions, k_positions, scale,
@@ -452,12 +486,15 @@ def ffn_layer(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
+    """The router over all ``n_experts`` (float32) and the weights of the
+    routed experts this device holds (``cfg.held_experts``), plus the
+    shared experts."""
     ks = jax.random.split(key, 5)
-    E, D, F = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
+    E, D, F = len(cfg.held_experts), cfg.d_model, cfg.d_ff_expert
     scale = 1.0 / math.sqrt(D)
     p = {
         "ln": jnp.zeros((D,), dtype),
-        "router": dense_init(ks[0], D, E, jnp.float32),
+        "router": dense_init(ks[0], D, cfg.n_experts, jnp.float32),
         "w_gate": (jax.random.normal(ks[1], (E, D, F), jnp.float32) * scale).astype(dtype),
         "w_up": (jax.random.normal(ks[2], (E, D, F), jnp.float32) * scale).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (E, F, D), jnp.float32) / math.sqrt(F)).astype(dtype),
@@ -469,18 +506,115 @@ def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     return p
 
 
+def _route(cfg: ModelConfig, p: dict, flat: jax.Array) -> tuple:
+    """Softmax over every expert's float32 router score, greedy top-k; the
+    gates renormalised over the k only under ``cfg.norm_topk_prob``.
+    Returns (probs [..., E], gates [..., k], experts [..., k])."""
+    logits = jnp.matmul(flat.astype(jnp.float32), p["router"],
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True),
+                                         1e-9)
+    return probs, gate_vals, gate_idx
+
+
+def _aux_loss(cfg: ModelConfig, probs, gate_idx) -> jax.Array:
+    """Switch-style load-balancing loss over every token."""
+    E = cfg.n_experts
+    lead = tuple(range(probs.ndim - 1))
+    density = jnp.mean(jax.nn.one_hot(gate_idx[..., 0], E), axis=lead)
+    mean_prob = jnp.mean(probs, axis=lead)
+    return E * jnp.sum(density * mean_prob) * cfg.router_aux_coef
+
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _grouped_ffn(act, rows, w_gate, w_up, w_down, sizes, layer):
+    """The held experts' SwiGLU over expert-sorted rows: the Pallas
+    grouped matmul where the program is lowered for a TPU, its jnp oracle
+    elsewhere (the CPU runs no Mosaic kernel natively)."""
+    from repro.kernels.grouped_matmul import ops, ref
+    return lax.platform_dependent(
+        rows, w_gate, w_up, w_down, sizes, layer,
+        tpu=functools.partial(ops.grouped_ffn, act=act),
+        default=functools.partial(ref.grouped_ffn, act=act))
+
+
+def _moe_dropless(cfg: ModelConfig, p: dict, x: jax.Array,
+                  layer=None) -> tuple:
+    """Every (token, slot) pair whose expert this device holds, computed:
+    the pairs are sorted by expert (pairs for experts held elsewhere after
+    them, never computed), each held expert's rows go through its SwiGLU
+    in one grouped matmul that reads its weights once, and ``gate x out``
+    is summed back per token.  Returns (residual output,
+    aux loss, load): load [B, S, held + 1] int32 counts each token's
+    pairs per held expert, the last column those sent elsewhere.
+    ``layer``: the experts' weights are a layer scan's whole stacks
+    ``[layers, held, ...]`` and this layer's index into them; None: one
+    layer's ``[held, ...]``."""
+    B, S, D = x.shape
+    k = cfg.experts_per_token
+    held = cfg.held_experts
+    Eh = len(held)
+    T = B * S
+    h = rms_norm(x, p["ln"], cfg.norm_eps)
+    flat = h.reshape(T, D)
+    with jax.named_scope("moe.route"):
+        probs, gate_vals, gate_idx = _route(cfg, p, flat)       # [T, k]
+        local = gate_idx - held.start
+        mine = (local >= 0) & (local < Eh)
+        slot_expert = jnp.where(mine, local, Eh).reshape(T * k)
+    with jax.named_scope("moe.dispatch"):
+        order = jnp.argsort(slot_expert, stable=True)           # [T*k]
+        sizes = jnp.bincount(slot_expert, length=Eh + 1).astype(jnp.int32)
+        rows = flat[order // k]                                 # [T*k, D]
+    with jax.named_scope("moe.experts"):
+        weights = [p[w] for w in EXPERT_WEIGHTS]
+        if layer is None:
+            weights, layer = [w[None] for w in weights], 0
+        y = _grouped_ffn(_act_fn(cfg.ffn_act), rows, *weights, sizes[:Eh],
+                         jnp.asarray(layer, jnp.int32))         # [T*k, D]
+    with jax.named_scope("moe.combine"):
+        back = jnp.argsort(order)           # pair -> its row in sorted order
+        y = y[back].reshape(T, k, D)
+        # rows of experts held elsewhere were never computed: select, so
+        # whatever they hold cannot reach the sum
+        w = jnp.where(mine, gate_vals, 0.0)
+        out = jnp.sum(jnp.where(mine[..., None], y.astype(jnp.float32), 0.0)
+                      * w[..., None], axis=1).astype(x.dtype)
+        out = out.reshape(B, S, D)
+    if "shared" in p:
+        sh, act = p["shared"], _act_fn(cfg.ffn_act)
+        out = out + (act(h @ sh["w_gate"]) * (h @ sh["w_up"])) @ sh["w_down"]
+    load = jax.nn.one_hot(slot_expert.reshape(T, k), Eh + 1,
+                          dtype=jnp.int32).sum(1).reshape(B, S, Eh + 1)
+    return x + out, _aux_loss(cfg, probs, gate_idx), load
+
+
 def moe_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
               capacity_factor: float = 1.25, n_groups: int = 1,
-              lossless: bool = False) -> tuple[jax.Array, jax.Array]:
-    """Capacity-bounded top-k MoE with grouped gather/scatter dispatch.
+              lossless: bool = False, layer=None) -> tuple:
+    """Top-k MoE.  Returns (residual output, router aux loss, load).
 
-    Tokens are split into ``n_groups`` dispatch groups (one per device shard
-    at run time — the launcher passes mesh size); capacity is per group, so
-    every intermediate is sharded along the group axis and nothing [T, E, C]-
-    sized ever materializes globally (TPU 'dropped' MoE; see DESIGN.md).
-
-    Returns (residual output, router aux loss).
+    ``lossless`` (serving): the dropless grouped path over the held
+    experts (``_moe_dropless``, reading stacked expert weights at
+    ``layer`` where it is given), whose load counts each token's pairs per
+    held expert.  Otherwise (training) capacity-bounded with grouped
+    gather/scatter dispatch: tokens are split into ``n_groups`` dispatch
+    groups (one per device shard at run time — the launcher passes mesh
+    size); capacity is per group, so every intermediate is sharded along
+    the group axis and nothing [T, E, C]-sized ever materializes globally
+    (TPU 'dropped' MoE; see DESIGN.md).  It holds every expert and
+    returns no load.
     """
+    if lossless:
+        return _moe_dropless(cfg, p, x, layer)
+    if cfg.expert_shard != (0, 1):
+        raise ValueError(f"{cfg.name}: the capacity-bounded MoE needs every "
+                         f"expert; this config holds {cfg.expert_shard}")
     B, S, D = x.shape
     E, topk = cfg.n_experts, cfg.experts_per_token
     T = B * S
@@ -489,18 +623,10 @@ def moe_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
     h = rms_norm(x, p["ln"], cfg.norm_eps)
     flat = h.reshape(G, Tg, D)
 
-    logits = flat.astype(jnp.float32) @ p["router"]            # [G, Tg, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, topk)               # [G, Tg, k]
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_vals, gate_idx = _route(cfg, p, flat)          # [G, Tg, k]
+    aux = _aux_loss(cfg, probs, gate_idx)
 
-    # aux load-balancing loss (Switch-style), computed over all tokens
-    density = jnp.mean(jax.nn.one_hot(gate_idx[..., 0], E), axis=(0, 1))
-    mean_prob = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(density * mean_prob) * cfg.router_aux_coef
-
-    capacity = (Tg * topk if lossless
-                else max(1, int(Tg * topk * capacity_factor / E)))
+    capacity = max(1, int(Tg * topk * capacity_factor / E))
     # position of each (token, slot) within its expert, per group
     onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)      # [G, Tg, k, E]
     flat_oh = onehot.reshape(G, Tg * topk, E)
@@ -536,4 +662,4 @@ def moe_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
     if "shared" in p:
         sh = p["shared"]
         out = out + (act(h @ sh["w_gate"]) * (h @ sh["w_up"])) @ sh["w_down"]
-    return x + out, aux
+    return x + out, aux, None

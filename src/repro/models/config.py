@@ -16,8 +16,9 @@ so that XLA compiles one body per layer class instead of one per layer.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 # Mixer kinds. "global"/"local" are softmax attention (local = sliding window),
 # "mla" is DeepSeek multi-head latent attention, "ssd" is Mamba-2 state space
@@ -38,6 +39,26 @@ class LayerSpec:
     @property
     def key(self) -> str:
         return f"{self.mixer}+{self.ffn}"
+
+
+class Yarn(NamedTuple):
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    the rotary frequencies are interpolated by ``factor`` below the
+    correction range set by ``beta_fast``/``beta_slow`` rotations over
+    ``original_max_position``, and attention logits grow by
+    ``mscale_all_dim``'s YaRN mscale squared."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -68,6 +89,7 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0      # gemma2-style softcap on attn logits
     final_logit_softcap: float = 0.0     # gemma2-style softcap on lm logits
     rope_theta: float = 10_000.0
+    rope_yarn: Optional[Yarn] = None     # YaRN scaling of the MLA rope
     qk_norm: bool = False
 
     # -- FFN -----------------------------------------------------------------
@@ -80,6 +102,11 @@ class ModelConfig:
     d_ff_expert: int = 0
     first_k_dense: int = 0               # DeepSeek: first k layers use dense FFN
     router_aux_coef: float = 0.0
+    norm_topk_prob: bool = True          # renormalise the top-k gates (Mixtral)
+    # (index, count): this device holds routed experts index * n / count
+    # to (index + 1) * n / count - 1 of every MoE layer; the router still
+    # scores all n_experts (expert parallelism across ``count`` devices)
+    expert_shard: tuple[int, int] = (0, 1)
 
     # -- MLA (DeepSeek-V2) ----------------------------------------------------
     q_lora_rank: int = 0
@@ -111,6 +138,17 @@ class ModelConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     emb_scale: bool = False              # gemma-style sqrt(d) embedding scale
+
+    def __post_init__(self):
+        # plain sequences (a config read back from JSON) take their types
+        if self.rope_yarn is not None and not isinstance(self.rope_yarn,
+                                                         Yarn):
+            object.__setattr__(self, "rope_yarn", Yarn(*self.rope_yarn))
+        object.__setattr__(self, "expert_shard", tuple(self.expert_shard))
+        index, count = self.expert_shard
+        if self.n_experts % count or not 0 <= index < count:
+            raise ValueError(f"expert_shard {self.expert_shard} does not "
+                             f"split {self.n_experts} experts")
 
     # ---------------------------------------------------------------------------
     def layers(self) -> tuple[LayerSpec, ...]:
@@ -171,6 +209,13 @@ class ModelConfig:
         if self.vocab_size % 16 == 0:
             return self.vocab_size
         return ((self.vocab_size + mult - 1) // mult) * mult
+
+    @property
+    def held_experts(self) -> range:
+        """The routed experts this device holds (``expert_shard``)."""
+        index, count = self.expert_shard
+        n = self.n_experts // count
+        return range(index * n, (index + 1) * n)
 
     @property
     def d_inner(self) -> int:  # SSD inner width
@@ -254,7 +299,8 @@ class ModelConfig:
             return 3 * self.d_model * self.d_ff
         if ffn == "moe":
             per_exp = 3 * self.d_model * self.d_ff_expert
-            return (self.n_experts + self.n_shared_experts) * per_exp + \
+            held = len(self.held_experts)
+            return (held + self.n_shared_experts) * per_exp + \
                 self.d_model * self.n_experts
         return 0
 

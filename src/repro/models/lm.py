@@ -10,13 +10,15 @@ layers and 512 devices.
   train    — full-sequence teacher forcing, remat'd scan bodies, aux losses;
   prefill  — full sequence, returns populated caches;
   decode   — one token per sequence against the cache.
+Its third output (``Aux``) holds the router loss and, for a MoE model
+served through the dropless path, each token's expert load.
 """
 
 from __future__ import annotations
 
 import hashlib
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -519,7 +521,9 @@ def insert_paged_prompt(cfg: ModelConfig, caches: dict, single: dict,
                 "ckv_pages": scatter(leaf["ckv_pages"], tables["global"],
                                      cpos, sl["ckv"][:, 0]),
                 "krope_pages": scatter(leaf["krope_pages"], tables["global"],
-                                       cpos, sl["krope"][:, 0]),
+                                       cpos,
+                                       mla_mod.pad_krope(cfg,
+                                                         sl["krope"][:, 0])),
             }}
         else:
             # ssd/rglru: O(1) recurrent state into the lane
@@ -653,15 +657,31 @@ def mask_cache_positions(cache: dict, true_len) -> dict:
 # forward
 # =============================================================================
 
+class Aux(NamedTuple):
+    """What a forward pass reports beside its logits and cache."""
+
+    loss: jax.Array                     # router load-balancing loss (train)
+    # [B, S, held + 1] int32, summed over the MoE layers: each token's
+    # (token, slot) pairs per held expert, the last column the pairs sent
+    # to experts held elsewhere; None without a dropless MoE layer
+    expert_load: Optional[jax.Array] = None
+
+
+def _add_load(a, b):
+    return b if a is None else a if b is None else a + b
+
+
 def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
                  positions, cache: Optional[dict], enc_out, impl: str,
                  n_groups: int, capacity_factor: float = 1.25,
                  moe_lossless: bool = False, unroll: bool = False,
                  paged_tables=None, window_tables=None, cross_tables=None,
                  layer=None, valid_len=None, shard_fn=None):
-    """One layer. Returns (h, new_cache_or_None, aux).  ``layer`` is the
-    layer's index into its segment's stacked page pools (paged mode)."""
+    """One layer. Returns (h, new_cache_or_None, aux loss, expert load or
+    None).  ``layer`` is the layer's index into its segment's stacked page
+    pools (paged mode)."""
     aux = jnp.zeros((), jnp.float32)
+    load = None
     new_cache: dict = {}
 
     if spec.mixer in ("global", "local"):
@@ -738,11 +758,13 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
     if spec.ffn == "dense":
         h = blocks.ffn_layer(cfg, p["ffn"], h)
     elif spec.ffn == "moe":
-        h, a = blocks.moe_layer(cfg, p["moe"], h, n_groups=n_groups,
-                                capacity_factor=capacity_factor,
-                                lossless=moe_lossless)
+        # serving scans hand the expert weights over as whole stacks
+        h, a, load = blocks.moe_layer(cfg, p["moe"], h, n_groups=n_groups,
+                                      capacity_factor=capacity_factor,
+                                      lossless=moe_lossless,
+                                      layer=layer if moe_lossless else None)
         aux = aux + a
-    return h, (new_cache if new_cache else None), aux
+    return h, (new_cache if new_cache else None), aux, load
 
 
 def _pool_keys(entry: dict) -> tuple:
@@ -761,14 +783,18 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                  valid_len=None, shard_fn=None):
     """Scan the segment's layers — its first ``repeats`` cycle repeats
     when given (the truncated draft pass; the rest keep their cache).
-    Returns (h, new segment cache or None, summed aux).
+    Returns (h, new segment cache or None, ``Aux`` summed over its layers).
 
     Page pools ride in the scan's *carry* as the whole stacked
     ``[seg.repeats, n_pages, ...]`` arrays: each layer writes its rows
     into, and gathers its pages from, its own index of the stack, so a
     step that donates the pools updates them in place instead of
     rebuilding a layer's pool per iteration.  Layer weights and every
-    other cache leaf (recurrent state, dense caches) stay in xs/ys."""
+    other cache leaf (recurrent state, dense caches) stay in xs/ys, but
+    for the dropless MoE (``moe_lossless``) the routed experts' weights
+    enter the body whole, like the pools, and the grouped-matmul kernel
+    reads the layer's experts from the stack: an xs slice handed to the
+    kernel would be copied out of the stack first."""
     run = seg.repeats if repeats is None else repeats
     names = [f"c{ci}" for ci in range(len(seg.cycle))]
     pools: dict = {c: {} for c in names}
@@ -778,6 +804,14 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                  for c in names}
         rest = {c: {k: v for k, v in seg_cache[c].items()
                     if k not in pools[c]} for c in names}
+    stacks: dict = {}
+    if moe_lossless:
+        for c, spec in zip(names, seg.cycle):
+            if spec.ffn == "moe":
+                moe = seg_p[c]["moe"]
+                stacks[c] = {w: moe[w] for w in blocks.EXPERT_WEIGHTS}
+                seg_p = {**seg_p, c: {**seg_p[c], "moe": {
+                    w: v for w, v in moe.items() if w not in stacks[c]}}}
     if run < seg.repeats:
         head = lambda t: jax.tree.map(lambda x: x[:run], t)
         seg_p, xs_rest = head(seg_p), head(rest)
@@ -789,38 +823,39 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
         ps, cs, layer = xs
         new_cs: dict = {}
         aux = jnp.zeros((), jnp.float32)
+        load = None
         for c, spec in zip(names, seg.cycle):
             lc = None if cs is None else {**cs[c], **pl[c]}
-            hh, nc, a = _apply_layer(cfg, spec, ps[c], hh,
-                                     positions=positions, cache=lc,
-                                     enc_out=enc_out, impl=impl,
-                                     n_groups=n_groups,
-                                     capacity_factor=capacity_factor,
-                                     moe_lossless=moe_lossless,
-                                     unroll=unroll,
-                                     paged_tables=paged_tables,
-                                     window_tables=window_tables,
-                                     cross_tables=cross_tables,
-                                     layer=layer, valid_len=valid_len,
-                                     shard_fn=shard_fn)
+            pc = ps[c]
+            if c in stacks:
+                pc = {**pc, "moe": {**pc["moe"], **stacks[c]}}
+            hh, nc, a, ld = _apply_layer(
+                cfg, spec, pc, hh, positions=positions, cache=lc,
+                enc_out=enc_out, impl=impl, n_groups=n_groups,
+                capacity_factor=capacity_factor, moe_lossless=moe_lossless,
+                unroll=unroll, paged_tables=paged_tables,
+                window_tables=window_tables, cross_tables=cross_tables,
+                layer=layer, valid_len=valid_len, shard_fn=shard_fn)
             aux = aux + a
+            load = _add_load(load, ld)
             if nc is not None:
                 pl = {**pl, c: {k: nc[k] for k in pl[c]}}
                 new_cs[c] = {k: v for k, v in nc.items() if k not in pl[c]}
-        return (hh, pl), (new_cs if new_cs else None, aux)
+        return (hh, pl), (new_cs if new_cs else None, aux, load)
 
     if remat:
         body = jax.checkpoint(body)
-    (h, pools), (new_rest, auxs) = lax.scan(
+    (h, pools), (new_rest, auxs, loads) = lax.scan(
         body, (h, pools), (seg_p, xs_rest, jnp.arange(run, dtype=jnp.int32)),
         unroll=run if unroll else 1)
+    aux = Aux(jnp.sum(auxs), None if loads is None else jnp.sum(loads, 0))
     if new_rest is None:
-        return h, None, jnp.sum(auxs)
+        return h, None, aux
     if run < seg.repeats:  # splice the run repeats over the untouched tail
         new_rest = jax.tree.map(
             lambda full, part: jnp.concatenate([part, full[run:]], axis=0),
             rest, new_rest)
-    return h, {c: {**new_rest[c], **pools[c]} for c in names}, jnp.sum(auxs)
+    return h, {c: {**new_rest[c], **pools[c]} for c in names}, aux
 
 
 def _encode(cfg: ModelConfig, params: dict, frontend_emb, *,
@@ -867,7 +902,7 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
             cross_tables: Optional[jax.Array] = None,
             valid_len=None, layer_cap: Optional[int] = None,
             shard_fn=None, unroll: bool = False):
-    """Returns (logits, new_cache_or_None, aux_loss).
+    """Returns (logits, new_cache_or_None, ``Aux``).
 
     tokens: [B, S] (decode: [B, 1]).
     positions: [S] absolute positions (decode: scalar array). Defaults to
@@ -938,7 +973,7 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
     h = shard_fn(h, "residual")
 
     # ---- decoder segments ---------------------------------------------------------
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = Aux(jnp.zeros((), jnp.float32))
     new_cache: dict = {}
     remaining = None if layer_cap is None else max(int(layer_cap), 1)
     for si, seg in enumerate(cfg.segments()):
@@ -962,7 +997,8 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
             cross_tables=cross_tables, valid_len=valid_len,
             shard_fn=shard_fn)
         h = shard_fn(h, "residual")
-        aux_total = aux_total + aux
+        aux_total = Aux(aux_total.loss + aux.loss,
+                        _add_load(aux_total.expert_load, aux.expert_load))
         if ncs is not None:
             new_cache[f"seg{si}"] = ncs
 

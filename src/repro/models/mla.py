@@ -4,6 +4,8 @@ The KV cache stores only the compressed latent c_kv [kv_lora] plus the shared
 RoPE key k_rope [qk_rope_dim]. Decode uses the *absorbed* form: W_uk folds
 into the query and W_uv into the output projection, so attention runs in the
 latent space (MQA with one 'head' of width kv_lora + rope per query head).
+A YaRN-scaled rope (``cfg.rope_yarn``) changes the rope frequencies and
+multiplies the softmax scale by the YaRN mscale squared (``softmax_scale``).
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .blocks import apply_rope, dense_init, rms_norm, attention
-from .config import ModelConfig
+from .config import ModelConfig, yarn_mscale
 
 
 def init_mla(key, cfg: ModelConfig, dtype) -> dict:
@@ -42,16 +44,45 @@ def init_mla_cache(cfg: ModelConfig, batch: int, kv_len: int, dtype) -> dict:
     }
 
 
+LANES = 128
+
+
+def krope_width(cfg: ModelConfig) -> int:
+    """Width of a rope-key pool row: ``qk_rope_dim`` rounded up to whole
+    128-lane rows.  A narrower minor dim makes the TPU lay the stacked pool
+    out page-minor, and every step would copy the whole pool into row
+    order and back; the padding columns stay zero."""
+    return -(-cfg.qk_rope_dim // LANES) * LANES
+
+
+def pad_krope(cfg: ModelConfig, rows: jax.Array) -> jax.Array:
+    """Rope-key rows [..., qk_rope_dim] -> pool rows [..., krope_width]."""
+    pad = krope_width(cfg) - rows.shape[-1]
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+
 def init_paged_mla_cache(cfg: ModelConfig, n_pages: int, block_size: int,
                          dtype) -> dict:
     """Physical block-pool cache for one MLA layer: the compressed latent
     (ckv) and shared rope key are per-token rows exactly like attention K/V,
-    so they page through the same global block tables.  ``n_pages`` includes
-    the trailing null/scratch page."""
+    so they page through the same global block tables; a rope-key row is
+    padded to ``krope_width``.  ``n_pages`` includes the trailing
+    null/scratch page."""
     return {
         "ckv_pages": jnp.zeros((n_pages, block_size, cfg.kv_lora_rank), dtype),
-        "krope_pages": jnp.zeros((n_pages, block_size, cfg.qk_rope_dim), dtype),
+        "krope_pages": jnp.zeros((n_pages, block_size, krope_width(cfg)),
+                                 dtype),
     }
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope) ** -0.5, times mscale(factor, mscale_all_dim)^2
+    under YaRN (DeepSeek-V2's published attention scale)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    y = cfg.rope_yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def _project(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
@@ -60,11 +91,12 @@ def _project(cfg: ModelConfig, p: dict, h: jax.Array, positions: jax.Array):
     nh = cfg.n_heads
     q = (h @ p["wq"]).reshape(B, S, nh, cfg.qk_nope_dim + cfg.qk_rope_dim)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_yarn)
     down = h @ p["wkv_down"]
     ckv, krope = jnp.split(down, [cfg.kv_lora_rank], axis=-1)
     ckv = rms_norm(ckv, p["kv_ln"], cfg.norm_eps)
-    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta,
+                       cfg.rope_yarn)[:, :, 0]
     return q_nope, q_rope, ckv, krope
 
 
@@ -95,7 +127,8 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
     q = sf(jnp.concatenate([q_nope, q_rope], axis=-1), "q_heads")
     k = sf(jnp.concatenate([k_nope, k_rope_b], axis=-1), "kv_heads")
     o = attention(q, k, v, q_positions=positions, k_positions=positions,
-                  causal=True, impl=impl, unroll=unroll)
+                  causal=True, impl=impl, unroll=unroll,
+                  scale=softmax_scale(cfg))
     out = o.reshape(B, S, nh * cfg.v_head_dim) @ p["wo"]
 
     new_cache = None
@@ -140,19 +173,20 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables, layer):
         ctx = pos[-1][None] + 1
         q_pos = pos[None]                                        # [1, S]
     ckv_pages, krope_pages = paged_write(
-        cache["ckv_pages"], cache["krope_pages"], tables, pos, ckv_t, krope_t,
-        layer)
+        cache["ckv_pages"], cache["krope_pages"], tables, pos, ckv_t,
+        pad_krope(cfg, krope_t), layer)
 
     bs = ckv_pages.shape[2]
     L = tables.shape[1] * bs
     ckv_c = ckv_pages[layer, tables].reshape(B, L, cfg.kv_lora_rank)
-    krope_c = krope_pages[layer, tables].reshape(B, L, cfg.qk_rope_dim)
+    krope_c = krope_pages[layer, tables].reshape(
+        B, L, krope_pages.shape[-1])[..., :cfg.qk_rope_dim]
     j = jnp.arange(L, dtype=jnp.int32)
     pos_c = jnp.where(j[None] < ctx[:, None], j[None], -1)       # [B, L]
 
     wk = p["wk_up"].reshape(cfg.kv_lora_rank, nh, cfg.qk_nope_dim)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bshr,bkr->bshk", q_lat, ckv_c) +
               jnp.einsum("bshd,bkd->bshk", q_rope, krope_c)).astype(jnp.float32)
     scores = scores * scale
@@ -182,7 +216,7 @@ def _mla_decode(cfg, p, x, h, positions, cache):
     # absorb W_uk: q_lat[h] = q_nope[h] @ W_uk[h]^T  -> [B, 1, nh, kv_lora]
     wk = p["wk_up"].reshape(cfg.kv_lora_rank, nh, cfg.qk_nope_dim)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bshr,bkr->bshk", q_lat, ckv_c) +
               jnp.einsum("bshd,bkd->bshk", q_rope, krope_c)).astype(jnp.float32)
     scores = scores * scale

@@ -103,6 +103,11 @@ class ServeStep:
     admitted: tuple = ()
     chunks: tuple = ()
     decoded: tuple = ()
+    # sparse-expert models: the (token, slot) pairs the programs whose
+    # results this step brought to the host routed to each held expert,
+    # summed over the MoE layers, then those routed to experts held
+    # elsewhere; None for a model without MoE layers
+    experts: tuple | None = None
 
 
 class _Phase:
@@ -184,6 +189,7 @@ class ServeTelemetry:
         self._total_accepted = 0
         self._total_rewound = 0
         self._starved_decode_steps = 0
+        self._expert_load = None
 
     def reset(self) -> None:
         """Drop all recorded steps and whole-run aggregates."""
@@ -201,6 +207,7 @@ class ServeTelemetry:
         self._total_accepted = 0
         self._total_rewound = 0
         self._starved_decode_steps = 0
+        self._expert_load = None
 
     def record_step(self, step: int, seconds: float, active_slots,
                     n_slots: int, blocks_in_use: int, n_blocks: int,
@@ -214,7 +221,8 @@ class ServeTelemetry:
                     cached_blocks: int = 0, drafted: int = 0,
                     accepted: int = 0, rewound_tokens: int = 0,
                     t0: float = 0.0, t1: float = 0.0, phases: dict = None,
-                    admitted=(), chunks=(), decoded=()) -> None:
+                    admitted=(), chunks=(), decoded=(),
+                    experts=None) -> None:
         self.steps.append(ServeStep(
             step=step, seconds=seconds, active_slots=tuple(active_slots),
             n_slots=n_slots, blocks_in_use=blocks_in_use, n_blocks=n_blocks,
@@ -230,7 +238,11 @@ class ServeTelemetry:
             accepted=accepted, rewound_tokens=rewound_tokens,
             t0=t0, t1=t1, phases=dict(phases or {}),
             admitted=tuple(admitted), chunks=tuple(chunks),
-            decoded=tuple(decoded)))
+            decoded=tuple(decoded), experts=experts))
+        if experts is not None:
+            self._expert_load = (list(experts) if self._expert_load is None
+                                 else [a + b for a, b in
+                                       zip(self._expert_load, experts)])
         # chunk work units are not emitted tokens — only completed prefills
         # (one greedy token each) and decode tokens count
         self._total_tokens += new_tokens + prefills
@@ -349,6 +361,27 @@ class ServeTelemetry:
         the quantity the router benchmark gates when comparing co-located
         against disaggregated prefill/decode."""
         return self._starved_decode_steps
+
+    def expert_load(self) -> list | None:
+        """Whole-run (token, slot) pairs per held expert, summed over the
+        MoE layers, then the pairs routed to experts held elsewhere; None
+        for a model without MoE layers."""
+        return None if self._expert_load is None else list(self._expert_load)
+
+    def expert_imbalance(self) -> float:
+        """Max over mean of the held experts' whole-run load (1 when
+        balanced, 0 before any load): what a rebalancing of expert
+        placement (paper §3) would act on."""
+        held = (self._expert_load or [])[:-1]
+        mean = sum(held) / len(held) if held else 0
+        return max(held) / mean if mean else 0.0
+
+    def absent_share(self) -> float:
+        """Share of the whole run's routed pairs sent to experts held
+        elsewhere (0 before any load)."""
+        load = self._expert_load or []
+        total = sum(load)
+        return load[-1] / total if total else 0.0
 
     # -- assistant bridge (paper §3) -------------------------------------------
     def device_interference(self, k: int) -> list:

@@ -165,7 +165,8 @@ class PagedKVStore:
     Owns a pair of page pools ``k_pages``/``v_pages`` of shape
     ``[n_layers, n_blocks + 1, block_size, width]`` — attention leaves pair
     lane-dense K/V rows (``width = n_kv_heads * head_dim``), MLA leaves
-    pair ckv/krope latent rows (the two pools may have different widths).
+    pair ckv/krope latent rows (the two pools may have different widths;
+    a krope row is zero-padded to whole 128-lane rows).
     Page ``n_blocks`` is the null block (see module docstring).  All
     updates are functional — methods replace ``self.k_pages``/``self.v_pages``
     with the updated arrays, so a store can also be *rebound* to pool
@@ -219,13 +220,16 @@ class PagedKVStore:
     # -- physical access ---------------------------------------------------------
     def write_token(self, table: list, pos: int, k, v) -> None:
         """Write one token's rows (``[n_layers, *row]``, each row
-        flattening to its pool's width — ``[n_layers, KV, hd]`` for K/V) at
-        logical position ``pos`` of the lane backed by ``table``."""
+        flattening to at most its pool's width — ``[n_layers, KV, hd]`` for
+        K/V; a narrower row fills the leading columns) at logical position
+        ``pos`` of the lane backed by ``table``."""
         block = table[pos // self.config.block_size]
         off = pos % self.config.block_size
         L = self.n_layers
-        self.k_pages = self.k_pages.at[:, block, off].set(k.reshape(L, -1))
-        self.v_pages = self.v_pages.at[:, block, off].set(v.reshape(L, -1))
+        self.k_pages = self.k_pages.at[:, block, off, :k[0].size].set(
+            k.reshape(L, -1))
+        self.v_pages = self.v_pages.at[:, block, off, :v[0].size].set(
+            v.reshape(L, -1))
 
     def gather_slot(self, table: list, context_len: int):
         """Reconstruct the lane's logical rows: ``[n_layers, context_len,

@@ -180,11 +180,23 @@ def make_bucketed_prefill_step(cfg: ModelConfig, impl: str = "chunked"):
     return prefill_step
 
 
+def _load_of(aux, rows: jax.Array):
+    """A step's expert load: ``aux.expert_load`` summed over the rows that
+    are real work (``rows``: [B, S] bool), [held + 1] int32; None without
+    a dropless MoE layer."""
+    if aux.expert_load is None:
+        return None
+    return jnp.sum(jnp.where(rows[..., None], aux.expert_load, 0), (0, 1))
+
+
 def make_paged_decode_step(cfg: ModelConfig, impl: str = "chunked"):
     """decode(params, caches, toks [B], pos [B], tables {group: [B, W]},
-    active [B] bool) -> (next_toks [B], caches). One batched step over every
-    lane; each lane writes its token's rows through its group tables into
-    the shared pools.  ``active`` masks the recurrent state update to the
+    active [B] bool) -> (next_toks [B], caches, load). One batched step
+    over every lane; each lane writes its token's rows through its group
+    tables into the shared pools.  ``load`` ([held + 1] int32, None
+    without MoE layers) counts the active lanes' (token, slot) pairs per
+    held expert over the MoE layers, the last entry those routed to
+    experts held elsewhere.  ``active`` masks the recurrent state update to the
     lanes actually decoding — inactive lanes (retired, or mid chunked
     prefill with carried state) must not absorb their garbage tokens.
     ``sample_args = (base_keys [B,2], temperature [B], top_k [B],
@@ -193,7 +205,7 @@ def make_paged_decode_step(cfg: ModelConfig, impl: str = "chunked"):
     greedy lanes (temperature 0) still take the argmax bitwise."""
     def decode_step(params, caches, toks, pos, tables, active,
                     sample_args=None):
-        logits, new_cache, _ = lm.forward(
+        logits, new_cache, aux = lm.forward(
             cfg, params, toks[:, None], positions=pos, cache=caches,
             mode="decode", impl=impl, paged_tables=tables.get("global"),
             window_tables=tables.get("window"),
@@ -207,14 +219,14 @@ def make_paged_decode_step(cfg: ModelConfig, impl: str = "chunked"):
             tkeys = jax.vmap(lambda k, p: sampling_mod.token_key(k, p))(
                 keys, pos + 1)
             next_tok = sampling_mod.sample_lanes(row, tkeys, temp, topk, topp)
-        return next_tok, new_cache
+        return next_tok, new_cache, _load_of(aux, active[:, None])
     return decode_step
 
 
 def make_chunk_prefill_step(cfg: ModelConfig, chunk: int,
                             impl: str = "chunked", embeds: bool = False):
     """chunk(params, caches, piece, start, rows {group: [W]}, last_idx,
-    slot, valid) -> (candidate_tok [1], caches).
+    slot, valid) -> (candidate_tok [1], caches, load).
 
     Processes one C-token slice of a prompt directly against the paged
     tree: writes the slice's rows through the lane's group tables (global
@@ -226,7 +238,8 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int,
     ``last_idx`` (only meaningful on the final slice).  ``valid`` counts
     the slice's real rows: pad rows of a final chunk freeze the recurrent
     state and are redirected to the null page.  Fixed C means exactly one
-    compile regardless of prompt lengths.
+    compile regardless of prompt lengths.  ``load`` is the decode step's
+    expert load over the slice's ``valid`` rows.
 
     ``embeds=True`` (modality-frontend archs): ``piece`` is a [1, C,
     d_model] slice of the precomputed decoder input rows
@@ -240,7 +253,7 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int,
         w_row = rows.get("window")
         x_row = rows.get("cross")
         sub = lm.lane_view(cfg, caches, slot)
-        logits, new_sub, _ = lm.forward(
+        logits, new_sub, aux = lm.forward(
             cfg, params, tokens=None if embeds else piece,
             input_embeds=piece if embeds else None,
             positions=positions, cache=sub,
@@ -253,7 +266,8 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int,
         last = lax.dynamic_index_in_dim(logits, last_idx, axis=1,
                                         keepdims=False)
         tok = _pick_token(last[:, :cfg.vocab_size], sample_args)
-        return tok, caches
+        rows = jnp.arange(chunk)[None] < valid
+        return tok, caches, _load_of(aux, rows)
     return chunk_step
 
 
@@ -572,6 +586,10 @@ class ContinuousEngine:
         self._stats_last = (0, 0, 0)
         # the step in progress (``run()`` makes one per step)
         self._spans = StepSpans(0)
+        # expert loads of programs dispatched and not yet brought to the
+        # host, and the sum of those the step in progress brought
+        self._loads: list = []
+        self._step_load = None
 
         if self.paged:
             self._init_paged()
@@ -689,7 +707,7 @@ class ContinuousEngine:
         # every jitted function that returns the pool tree takes it donated
         # (``_jit_donating``); the ``_caches`` setter rebinds the stores to
         # the returned pools, so nothing keeps a donated one
-        self._decode_p = _jit_donating(
+        self._decode_prog = _jit_donating(
             make_paged_decode_step(self.cfg, self.impl), 1)
         if self.prefill_chunk:
             self._chunk = _jit_donating(make_chunk_prefill_step(
@@ -755,6 +773,26 @@ class ContinuousEngine:
 
         self._insert_p = _jit_donating(paged_insert, 0)
         self._lane_set = jax.jit(lane_set)
+
+    def _decode_p(self, params, caches, toks, pos, tables, active,
+                  sample_args=None) -> tuple:
+        """The paged decode program: (next_toks, caches); its expert load
+        waits for the step's sync (``_sync``)."""
+        toks, caches, load = self._decode_prog(params, caches, toks, pos,
+                                               tables, active, sample_args)
+        if load is not None:
+            self._loads.append(load)
+        return toks, caches
+
+    def _sync(self, toks) -> np.ndarray:
+        """Bring ``toks`` to the host, and with them, in the same transfer
+        call, every expert load waiting; their sum joins the step's."""
+        host, loads = jax.device_get((toks, self._loads))
+        self._loads = []
+        for load in loads:
+            self._step_load = (load if self._step_load is None
+                               else self._step_load + load)
+        return np.asarray(host)
 
     @functools.cached_property
     def _fresh(self) -> dict:
@@ -1076,12 +1114,14 @@ class ContinuousEngine:
                 if fresh or freed:
                     self._refresh_row(slot, "window")
             last = total - 1 - start           # only valid on the final chunk
-            tok, self._caches = self._chunk(
+            tok, self._caches, load = self._chunk(
                 self.params, self._caches, piece[None],
                 jnp.asarray(start, jnp.int32), self._rows[slot],
                 jnp.asarray(min(max(last, 0), C - 1), jnp.int32),
                 jnp.asarray(slot, jnp.int32), jnp.asarray(valid, jnp.int32),
                 self._first_token_args(slot, total))
+            if load is not None:
+                self._loads.append(load)
             self._prefilling[slot][1] = done + 1
             if start + C < total:
                 return False
@@ -1092,7 +1132,7 @@ class ContinuousEngine:
             act.first_token_step = self._now
         # the first token's device -> host sync, apart from the dispatch
         with spans.span("engine.sync", what="chunk"):
-            act.tokens.append(int(tok[0]))
+            act.tokens.append(int(self._sync(tok)[0]))
         return True
 
     def _finish(self, slot: int) -> list:
@@ -1404,7 +1444,7 @@ class ContinuousEngine:
             self._toks = toks
             self._pos = self._pos + 1
         with spans.span("engine.sync", what="decode"):
-            toks_host = np.asarray(toks)       # one device->host transfer
+            toks_host = self._sync(toks)       # one device->host transfer
         new_tokens = 0
         for slot in decoding:
             act = self.scheduler.active.get(slot)
@@ -1459,6 +1499,7 @@ class ContinuousEngine:
             self._stats_last = cur
             shared = self.allocator.shared_saved_bytes()
             cached = self.allocator.cached_blocks()
+            experts, self._step_load = self._step_load, None
         t1 = time.perf_counter()
         self.telemetry.record_step(
             step=spans.step, seconds=t1 - spans.t0,
@@ -1477,4 +1518,5 @@ class ContinuousEngine:
             drafted=drafted, accepted=accepted, rewound_tokens=rewound,
             t0=spans.t0, t1=t1, phases=spans.phases,
             admitted=spans.admitted, chunks=spans.chunks,
-            decoded=spans.decoded)
+            decoded=spans.decoded,
+            experts=None if experts is None else tuple(experts.tolist()))

@@ -35,7 +35,7 @@ def _layer_fwd(cfg: ModelConfig, spec: LayerSpec, p: dict, x, positions):
     if spec.ffn == "dense":
         x = blocks.ffn_layer(cfg, p["ffn"], x)
     elif spec.ffn == "moe":
-        x, _ = blocks.moe_layer(cfg, p["moe"], x, n_groups=1)
+        x, _, _ = blocks.moe_layer(cfg, p["moe"], x, n_groups=1)
     return x
 
 

@@ -63,7 +63,7 @@ def make_train_step(cfg: ModelConfig, lr_fn: Callable,
             unroll=tcfg.unroll)
         lg = logits[:, F:] if F else logits
         ce = cross_entropy(lg, batch["labels"])
-        return ce + aux, {"ce": ce, "aux": aux}
+        return ce + aux.loss, {"ce": ce, "aux": aux.loss}
 
     def train_step(params, opt_state, batch, step):
         if tcfg.grad_accum > 1:

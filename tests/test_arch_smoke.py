@@ -25,7 +25,7 @@ def test_forward_shapes_and_finite(arch):
     F = cfg.frontend_tokens if (cfg.frontend and not cfg.n_enc_layers) else 0
     assert logits.shape == (B, S + F, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
-    assert bool(jnp.isfinite(aux))
+    assert bool(jnp.isfinite(aux.loss))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

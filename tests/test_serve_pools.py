@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get
-from repro.models import lm
+from repro.models import lm, mla
 from repro.serve import ContinuousEngine
 from repro.serve import engine as engine_mod
 
@@ -143,7 +143,14 @@ def test_gather_slot_returns_the_rows_the_steps_wrote(arch, monkeypatch):
             layers = want.shape[0]
             want = np.asarray(want[:, 0, :start]).reshape(layers, start, -1)
             rows = np.asarray(rows)
-            assert rows.shape[-1] == want.shape[-1]
+            # an MLA rope-key row is padded to whole 128-lane rows with
+            # zeros (mla.krope_width); every other row is its pool's width
+            width = want.shape[-1]
+            assert rows.shape[-1] == (mla.krope_width(cfg)
+                                      if width == cfg.qk_rope_dim
+                                      and cfg.kv_lora_rank else width)
+            assert not np.any(rows[..., width:])
+            rows = rows[..., :width]
             np.testing.assert_array_equal(rows[:, :start], want)
             # three decode steps wrote positions start .. start + 2
             written = np.abs(rows[:, start:]).sum(axis=(0, 2)) > 0
@@ -162,7 +169,8 @@ def test_block_bytes_counts_lane_dense_rows(arch, monkeypatch):
     assert len(leaves) == len(eng.allocator.stores) > 0
     for (_, keys, _), store in zip(leaves, eng.allocator.stores):
         if keys[0] == "ckv_pages":
-            width = cfg.kv_lora_rank + cfg.qk_rope_dim
+            width = cfg.kv_lora_rank + mla.krope_width(cfg)
+            assert mla.krope_width(cfg) % 128 == 0
         else:
             kv_row = cfg.n_kv_heads * cfg.head_dim
             assert store.k_pages.shape[2:] == store.v_pages.shape[2:] \

@@ -1,4 +1,4 @@
-"""The four Pallas kernels at real widths, compiled for a described TPU v5e.
+"""The Pallas kernels at real widths, compiled for a described TPU v5e.
 
 The TPU compiler is installed without a chip and compiles for a chip that
 is described, not attached.  It refuses what interpret mode accepts (tiles
@@ -79,6 +79,17 @@ def _rglru():
                         ((B, S, W), jnp.float32)], {}
 
 
+def _grouped(rows):
+    from repro.kernels.grouped_matmul import grouped_ffn
+    # deepseek-v2-lite: 26 MoE layers of 16 held experts of 2048 -> 1408 ->
+    # 2048; the rows of 24 decode lanes or of a 256-row chunk, 6 pairs each
+    L, E, d, ff = 26, 16, 2048, 1408
+    bf = jnp.bfloat16
+    return grouped_ffn, [((rows, d), bf), ((L, E, d, ff), bf),
+                         ((L, E, d, ff), bf), ((L, E, ff, d), bf),
+                         ((E,), jnp.int32), ((), jnp.int32)], {}
+
+
 CASES = {
     "paged_attention_mha": lambda: _paged(36),
     "paged_attention_gqa": lambda: _paged(4),
@@ -86,6 +97,8 @@ CASES = {
     "flash_attention_gqa": lambda: _flash(4),
     "ssd_scan": _ssd,
     "rglru_scan": _rglru,
+    "grouped_matmul_decode": lambda: _grouped(24 * 6),
+    "grouped_matmul_chunk": lambda: _grouped(256 * 6),
 }
 
 
@@ -114,24 +127,18 @@ def _pool_ops(hlo: str, n_pages: int) -> list[str]:
     return found
 
 
-@pytest.fixture(scope="module")
-def paged_steps(one_chip):
-    """minicpm-2b at published widths in bf16, the depth cut to 4 layers,
-    served as the benchmark serves it (4 lanes x 2048 positions, blocks of
-    16, chunks of 256): the engine's own decode and chunk programs, built
-    as on a chip (cache tree donated), compiled for one described v5e."""
-    import dataclasses
-
-    from repro.configs import get
+def _engine_programs(one_chip, cfg, kv_len: int, lanes: int):
+    """``cfg`` served as the benchmark serves it (blocks of 16, chunks of
+    256): the engine's own decode and chunk programs, built as on a chip
+    (cache tree donated), compiled for one described v5e."""
     from repro.models import lm
     from repro.serve import engine as engine_mod
 
-    cfg = dataclasses.replace(get("minicpm-2b"), n_layers=4)
     donates = engine_mod._donates
     engine_mod._donates = lambda: True
     try:
         eng = engine_mod.ContinuousEngine(
-            cfg, None, kv_len=2048, n_slots=4, dtype=jnp.bfloat16,
+            cfg, None, kv_len=kv_len, n_slots=lanes, dtype=jnp.bfloat16,
             paged=True, prefill_chunk=256, block_size=16)
     finally:
         engine_mod._donates = donates
@@ -141,18 +148,30 @@ def paged_steps(one_chip):
         cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
     caches = tree(eng._caches)
     i32 = lambda *shape: sds(jax.ShapeDtypeStruct(shape, jnp.int32))
-    lanes = tree((eng._skeys, eng._temp, eng._topk, eng._topp))
-    decode = eng._decode_p.lower(
+    lane_args = tree((eng._skeys, eng._temp, eng._topk, eng._topp))
+    decode = eng._decode_prog.lower(
         params, caches, tree(eng._toks), tree(eng._pos), tree(eng._tables),
-        sds(jax.ShapeDtypeStruct((4,), jnp.bool_)), lanes).compile()
+        sds(jax.ShapeDtypeStruct((lanes,), jnp.bool_)), lane_args).compile()
     first = tree((eng._skeys[0], eng._temp[0], eng._topk[0], eng._topp[0]))
     chunk = eng._chunk.lower(
-        params, caches, i32(1, 256), i32(), {"global": i32(128)}, i32(),
-        i32(), i32(), first).compile()
+        params, caches, i32(1, 256), i32(), {"global": i32(kv_len // 16)},
+        i32(), i32(), i32(), first).compile()
     pool_bytes = sum(x.size * x.dtype.itemsize
                      for x in jax.tree.leaves(eng._caches))
     n_pages = eng.allocator.config.n_blocks + 1
     return {"decode": decode, "chunk": chunk}, pool_bytes, n_pages
+
+
+@pytest.fixture(scope="module")
+def paged_steps(one_chip):
+    """minicpm-2b at published widths in bf16, the depth cut to 4 layers,
+    at the benchmark's 4 lanes x 2048 positions."""
+    import dataclasses
+
+    from repro.configs import get
+
+    cfg = dataclasses.replace(get("minicpm-2b"), n_layers=4)
+    return _engine_programs(one_chip, cfg, kv_len=2048, lanes=4)
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])
@@ -164,3 +183,65 @@ def test_paged_step_updates_the_pools_in_place(paged_steps, step):
         mem.alias_size_in_bytes, pool_bytes)
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
     assert _pool_ops(compiled.as_text(), n_pages) == []
+
+
+# -- MLA + MoE: the held experts behind the grouped matmul --------------------
+
+@pytest.fixture(scope="module")
+def moe_steps(one_chip):
+    """deepseek-v2-lite at published widths in bf16, 4 layers (the dense
+    one, then 3 MoE layers), holding 16 of the 64 experts, at the
+    benchmark's 24 lanes x 4096 positions."""
+    import dataclasses
+
+    from repro.configs import get
+
+    cfg = dataclasses.replace(get("deepseek-v2-lite-16b"), n_layers=4,
+                              expert_shard=(0, 4))
+    return _engine_programs(one_chip, cfg, kv_len=4096, lanes=24)
+
+
+def _dispatch_shapes(hlo: str, experts: int, pairs: int) -> list[str]:
+    """Instructions whose shape has an [experts, pairs] pair of dims, or
+    experts x pairs rows: the lossless capacity dispatch's buffers."""
+    found = []
+    for line in hlo.splitlines():
+        for dims in re.findall(r"\[([\d,]*)\]", line.split(" = ")[-1][:200]):
+            d = [int(x) for x in dims.split(",") if x]
+            if any(a == experts and b == pairs for a, b in zip(d, d[1:])) \
+                    or any(x in (experts * pairs, experts * pairs + 1)
+                           for x in d):
+                found.append(line.strip()[:160])
+    return found
+
+
+def _memory_space_move(line: str) -> bool:
+    """An async copy that only moves a buffer between memory spaces: its
+    shapes and layouts are the same but for the ``S(n)`` space."""
+    shapes = re.findall(r"(\w+\[[\d,]*\]\{[^}]*\})", line.split(" = ")[1])
+    plain = {re.sub(r"S\(\d+\)", "", x) for x in shapes
+             if not x.startswith("u32[]")}
+    return re.search(r" copy-(start|done)\(", line) is not None \
+        and len(plain) == 1
+
+
+@pytest.mark.parametrize("step,rows", [("decode", 24), ("chunk", 256)])
+def test_moe_step_runs_the_grouped_matmul_in_place(moe_steps, step, rows):
+    """The held experts run in the grouped-matmul kernel (a Mosaic custom
+    call), no [E, T*k, D] dispatch buffer is left, and the pools are
+    aliased and never copied into another layout.  XLA may keep the dense
+    layer's one-layer rope-key pool (25 MB) in on-chip memory across the
+    decode step: a move between memory spaces, in the same layout.  The
+    kernel reads each layer's experts from the stacked weights: no
+    [16, 2048, 1408] slice of them is made."""
+    programs, pool_bytes, n_pages = moe_steps
+    compiled = programs[step]
+    hlo = compiled.as_text()
+    assert re.search(r"%grouped_matmul[.\d]* = .*custom_call_target="
+                     r"\"tpu_custom_call\"", hlo)
+    assert _dispatch_shapes(hlo, 16, rows * 6) == []
+    assert not re.search(r"= bf16\[16,(2048,1408|1408,2048)\]", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert [x for x in _pool_ops(hlo, n_pages)
+            if not _memory_space_move(x)] == []
