@@ -8,6 +8,8 @@ take — the oracle is only ever called explicitly).
 
 Packages: ``flash_attention`` (fused train/prefill attention),
 ``paged_attention`` (block-table decode attention over the physical paged
-KV cache), ``ssd_scan`` (Mamba-2 chunked scan), ``rglru_scan`` (Griffin
-gated linear recurrence).
+KV cache), ``mla_decode`` (MLA decode attention over each lane's live
+latent pages), ``grouped_matmul`` (grouped expert FFN), ``ssd_scan``
+(Mamba-2 chunked scan), ``rglru_scan`` (Griffin gated linear
+recurrence).
 """

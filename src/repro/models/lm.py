@@ -676,10 +676,11 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
                  n_groups: int, capacity_factor: float = 1.25,
                  moe_lossless: bool = False, unroll: bool = False,
                  paged_tables=None, window_tables=None, cross_tables=None,
-                 layer=None, valid_len=None, shard_fn=None):
+                 layer=None, valid_len=None, context_lens=None,
+                 shard_fn=None):
     """One layer. Returns (h, new_cache_or_None, aux loss, expert load or
     None).  ``layer`` is the layer's index into its segment's stacked page
-    pools (paged mode)."""
+    pools (paged mode); ``context_lens`` reaches the MLA layers only."""
     aux = jnp.zeros((), jnp.float32)
     load = None
     new_cache: dict = {}
@@ -701,6 +702,7 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p: dict, h, *,
                                  cache=cache.get("mla") if cache else None,
                                  impl=impl, unroll=unroll,
                                  paged_tables=paged_tables, layer=layer,
+                                 context_lens=context_lens,
                                  shard_fn=shard_fn)
         if c is not None:
             new_cache["mla"] = c
@@ -780,7 +782,7 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                  capacity_factor: float = 1.25,
                  moe_lossless: bool = False, unroll: bool = False,
                  paged_tables=None, window_tables=None, cross_tables=None,
-                 valid_len=None, shard_fn=None):
+                 valid_len=None, context_lens=None, shard_fn=None):
     """Scan the segment's layers — its first ``repeats`` cycle repeats
     when given (the truncated draft pass; the rest keep their cache).
     Returns (h, new segment cache or None, ``Aux`` summed over its layers).
@@ -835,7 +837,8 @@ def _run_segment(cfg: ModelConfig, seg: Segment, seg_p: dict, h, *,
                 capacity_factor=capacity_factor, moe_lossless=moe_lossless,
                 unroll=unroll, paged_tables=paged_tables,
                 window_tables=window_tables, cross_tables=cross_tables,
-                layer=layer, valid_len=valid_len, shard_fn=shard_fn)
+                layer=layer, valid_len=valid_len, context_lens=context_lens,
+                shard_fn=shard_fn)
             aux = aux + a
             load = _add_load(load, ld)
             if nc is not None:
@@ -900,7 +903,8 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
             paged_tables: Optional[jax.Array] = None,
             window_tables: Optional[jax.Array] = None,
             cross_tables: Optional[jax.Array] = None,
-            valid_len=None, layer_cap: Optional[int] = None,
+            valid_len=None, context_lens: Optional[jax.Array] = None,
+            layer_cap: Optional[int] = None,
             shard_fn=None, unroll: bool = False):
     """Returns (logits, new_cache_or_None, ``Aux``).
 
@@ -923,6 +927,10 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
       (bucketed prefill tails, final prefill chunks); attention caches
       must not let them displace real rows and recurrent state freezes
       past them.
+    context_lens: [B] paged decode only — the rows each lane has resident
+      (``pos + 1``), 0 for a lane that is not decoding (free, or mid
+      chunked prefill).  Only MLA layers read it: their decode kernel
+      reads those rows and no others.  None reads ``pos + 1`` everywhere.
     layer_cap: run only the first ``layer_cap`` decoder layers (rounded
       *up* to whole cycle repeats within a segment, so a heterogeneous
       cycle is never split) before the shared final norm + unembed — the
@@ -995,7 +1003,7 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
             moe_lossless=moe_lossless, unroll=unroll,
             paged_tables=paged_tables, window_tables=window_tables,
             cross_tables=cross_tables, valid_len=valid_len,
-            shard_fn=shard_fn)
+            context_lens=context_lens, shard_fn=shard_fn)
         h = shard_fn(h, "residual")
         aux_total = Aux(aux_total.loss + aux.loss,
                         _add_load(aux_total.expert_load, aux.expert_load))

@@ -104,6 +104,7 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
               positions: jax.Array, cache: Optional[dict] = None,
               impl: str = "chunked", unroll: bool = False,
               paged_tables: Optional[jax.Array] = None, layer=None,
+              context_lens=None,
               shard_fn=None) -> tuple[jax.Array, Optional[dict]]:
     B, S, D = x.shape
     nh = cfg.n_heads
@@ -113,7 +114,7 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
         assert paged_tables is not None, "paged MLA cache needs block tables"
         assert layer is not None, "paged MLA cache needs the layer's index"
         return _mla_paged(cfg, p, x, h, positions, cache, paged_tables,
-                          layer)
+                          layer, context_lens)
 
     if cache is not None and S == 1:
         return _mla_decode(cfg, p, x, h, positions, cache)
@@ -142,21 +143,27 @@ def mla_layer(cfg: ModelConfig, p: dict, x: jax.Array, *,
     return x + out, new_cache
 
 
-def _mla_paged(cfg, p, x, h, positions, cache, tables, layer):
+def _mla_paged(cfg, p, x, h, positions, cache, tables, layer,
+               context_lens=None):
     """Absorbed attention over block-table-paged latents.
 
     ``cache`` holds the segment's stacked ``[layers, n_pages, block_size,
     *]`` latent pools, carried through the layer scan; this layer writes
-    its rows into, and gathers its pages from, index ``layer``.
+    its rows into, and reads its pages from, index ``layer``.
 
     Two shapes, mirroring the paged attention layer: batched decode (x is
     [B, 1, D], ``positions`` = [B] per-lane absolute positions) and chunked
     prefill (x is [1, C, D], ``positions`` = [C] the chunk's rows).  The
-    latent rows are written through the tables first, then the lane's
-    logical view is gathered back in ascending position order — the same
-    layout the dense cache stores (slot == position), so with the engine's
-    ``kv_len == max_blocks * block_size`` guarantee the decode arithmetic
-    is exactly ``_mla_decode``'s over identical operands.
+    latent rows are written through the tables first, then attended.
+
+    Decode is lowered per platform (``_decode_attend``): on a TPU the
+    ``mla_decode`` kernel reads each lane's first ``context_lens[b]`` rows
+    (``pos + 1`` when not given) and nothing else; elsewhere, and for a
+    chunk, ``_gathered_attend`` gathers the lane's whole logical view in
+    ascending position order — the layout the dense cache stores (slot ==
+    position), so with the engine's ``kv_len == max_blocks * block_size``
+    guarantee the decode arithmetic is exactly ``_mla_decode``'s over
+    identical operands.
     """
     from .blocks import paged_write
 
@@ -165,17 +172,39 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables, layer):
     if S == 1:  # batched decode: one token per lane, per-lane positions
         pos = positions.reshape(-1)                              # [B]
         q_nope, q_rope, ckv_t, krope_t = _project(cfg, p, h, pos[:, None])
-        ctx = pos + 1                 # resident incl. the token just written
-        q_pos = pos[:, None]                                     # [B, 1]
     else:       # chunk prefill: B == 1 lane, S == chunk rows
         pos = positions.reshape(-1)                              # [S]
         q_nope, q_rope, ckv_t, krope_t = _project(cfg, p, h, pos)
-        ctx = pos[-1][None] + 1
-        q_pos = pos[None]                                        # [1, S]
     ckv_pages, krope_pages = paged_write(
         cache["ckv_pages"], cache["krope_pages"], tables, pos, ckv_t,
         pad_krope(cfg, krope_t), layer)
 
+    wk = p["wk_up"].reshape(cfg.kv_lora_rank, nh, cfg.qk_nope_dim)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
+    layer = jnp.asarray(layer, jnp.int32)
+    if S == 1:
+        lens = pos + 1 if context_lens is None else context_lens
+        o_lat = _decode_attend(cfg, q_lat[:, 0], q_rope[:, 0], ckv_pages,
+                               krope_pages, tables, lens, layer, pos,
+                               x.dtype)[:, None]
+    else:
+        o_lat = _gathered_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages,
+                                 tables, layer, pos[-1][None] + 1, pos[None],
+                                 x.dtype)
+    wv = p["wv_up"].reshape(cfg.kv_lora_rank, nh, cfg.v_head_dim)
+    o = jnp.einsum("bshr,rhd->bshd", o_lat, wv)
+    out = o.reshape(B, S, nh * cfg.v_head_dim) @ p["wo"]
+    return x + out, {"ckv_pages": ckv_pages, "krope_pages": krope_pages}
+
+
+def _gathered_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages, tables,
+                     layer, ctx, q_pos, dtype):
+    """Latent attention over each lane's whole table, gathered from layer
+    ``layer`` of the pools: rows at or past ``ctx`` [B], and rows after a
+    query's own position ``q_pos`` [B, S], are masked.  q_lat: [B, S, H,
+    R]; q_rope: [B, S, H, rope].  Returns o_lat [B, S, H, R] in
+    ``dtype``."""
+    B = tables.shape[0]
     bs = ckv_pages.shape[2]
     L = tables.shape[1] * bs
     ckv_c = ckv_pages[layer, tables].reshape(B, L, cfg.kv_lora_rank)
@@ -183,22 +212,37 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables, layer):
         B, L, krope_pages.shape[-1])[..., :cfg.qk_rope_dim]
     j = jnp.arange(L, dtype=jnp.int32)
     pos_c = jnp.where(j[None] < ctx[:, None], j[None], -1)       # [B, L]
-
-    wk = p["wk_up"].reshape(cfg.kv_lora_rank, nh, cfg.qk_nope_dim)
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
-    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bshr,bkr->bshk", q_lat, ckv_c) +
               jnp.einsum("bshd,bkd->bshk", q_rope, krope_c)).astype(jnp.float32)
-    scores = scores * scale
+    scores = scores * softmax_scale(cfg)
     valid = (pos_c[:, None, :] >= 0) & \
         (pos_c[:, None, :] <= q_pos[:, :, None])                 # [B, S, L]
     scores = jnp.where(valid[:, :, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bshk,bkr->bshr", probs, ckv_c)
-    wv = p["wv_up"].reshape(cfg.kv_lora_rank, nh, cfg.v_head_dim)
-    o = jnp.einsum("bshr,rhd->bshd", o_lat, wv)
-    out = o.reshape(B, S, nh * cfg.v_head_dim) @ p["wo"]
-    return x + out, {"ckv_pages": ckv_pages, "krope_pages": krope_pages}
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bshk,bkr->bshr", probs, ckv_c)
+
+
+def _decode_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages, tables, lens,
+                   layer, pos, dtype):
+    """One decode row per lane (q_lat [B, H, R], q_rope [B, H, rope]) ->
+    o_lat [B, H, R].  Where the program is lowered for a TPU, the
+    ``mla_decode`` kernel reads lane ``b``'s first ``lens[b]`` rows in
+    place from the stacked pools (none for ``lens[b] == 0``, a lane that
+    is not decoding); elsewhere the gather path attends up to ``pos + 1``,
+    the arithmetic the CPU's token-identity tests pin."""
+    from repro.kernels.mla_decode import ops
+
+    def kernel(ql, qr, cp, kp, t, n, lyr, ps):
+        return ops.mla_decode(ql, qr, cp, kp, t, n, lyr,
+                              scale=softmax_scale(cfg))
+
+    def gathered(ql, qr, cp, kp, t, n, lyr, ps):
+        return _gathered_attend(cfg, ql[:, None], qr[:, None], cp, kp, t,
+                                lyr, ps + 1, ps[:, None], dtype)[:, 0]
+
+    return lax.platform_dependent(q_lat, q_rope, ckv_pages, krope_pages,
+                                  tables, lens, layer, pos, tpu=kernel,
+                                  default=gathered)
 
 
 def _mla_decode(cfg, p, x, h, positions, cache):

@@ -191,25 +191,31 @@ def _load_of(aux, rows: jax.Array):
 
 def make_paged_decode_step(cfg: ModelConfig, impl: str = "chunked"):
     """decode(params, caches, toks [B], pos [B], tables {group: [B, W]},
-    active [B] bool) -> (next_toks [B], caches, load). One batched step
+    lens [B] int32) -> (next_toks [B], caches, load). One batched step
     over every lane; each lane writes its token's rows through its group
-    tables into the shared pools.  ``load`` ([held + 1] int32, None
-    without MoE layers) counts the active lanes' (token, slot) pairs per
-    held expert over the MoE layers, the last entry those routed to
-    experts held elsewhere.  ``active`` masks the recurrent state update to the
-    lanes actually decoding — inactive lanes (retired, or mid chunked
-    prefill with carried state) must not absorb their garbage tokens.
+    tables into the shared pools.  ``lens`` is each lane's resident length
+    after this step's write (``pos + 1``) where the lane decodes, and 0
+    where it does not (free, or mid chunked prefill): the MLA decode
+    kernel reads that many rows of a lane, so an idle lane costs no reads
+    whatever its stale position.  The lanes with ``lens > 0`` are the
+    active ones.  ``load`` ([held + 1] int32, None without MoE layers)
+    counts the active lanes' (token, slot) pairs per held expert over the
+    MoE layers, the last entry those routed to experts held elsewhere.
+    The active mask also masks the recurrent state update to the lanes
+    actually decoding — inactive lanes must not absorb their garbage
+    tokens.
     ``sample_args = (base_keys [B,2], temperature [B], top_k [B],
     top_p [B])`` turns the fused argmax into the per-lane sampler (the
     token decided this step sits at ``pos + 1``, which derives its key);
     greedy lanes (temperature 0) still take the argmax bitwise."""
-    def decode_step(params, caches, toks, pos, tables, active,
+    def decode_step(params, caches, toks, pos, tables, lens,
                     sample_args=None):
+        active = lens > 0
         logits, new_cache, aux = lm.forward(
             cfg, params, toks[:, None], positions=pos, cache=caches,
             mode="decode", impl=impl, paged_tables=tables.get("global"),
             window_tables=tables.get("window"),
-            cross_tables=tables.get("cross"))
+            cross_tables=tables.get("cross"), context_lens=lens)
         new_cache = lm.freeze_state_lanes(cfg, new_cache, caches, active)
         row = logits[:, -1, :cfg.vocab_size]
         if sample_args is None:
@@ -774,12 +780,12 @@ class ContinuousEngine:
         self._insert_p = _jit_donating(paged_insert, 0)
         self._lane_set = jax.jit(lane_set)
 
-    def _decode_p(self, params, caches, toks, pos, tables, active,
+    def _decode_p(self, params, caches, toks, pos, tables, lens,
                   sample_args=None) -> tuple:
         """The paged decode program: (next_toks, caches); its expert load
         waits for the step's sync (``_sync``)."""
         toks, caches, load = self._decode_prog(params, caches, toks, pos,
-                                               tables, active, sample_args)
+                                               tables, lens, sample_args)
         if load is not None:
             self._loads.append(load)
         return toks, caches
@@ -1429,13 +1435,19 @@ class ContinuousEngine:
                 self._record_step((), prefills, 0)
                 self._now = now + 1
                 return True
-        with spans.span("engine.decode", lanes=len(decoding)):
+        decode_pages = 0
+        if self.paged:
+            # host arithmetic only: each decoding lane's rows after this
+            # step's write, 0 for every other lane, and the pages they hold
+            lens = np.zeros((self.n_slots,), np.int32)
+            lens[decoding] = [self._host_pos[s] + 1 for s in decoding]
+            decode_pages = int(np.sum(-(-lens // self.block_size)))
+        with spans.span("engine.decode", lanes=len(decoding),
+                        pages=decode_pages):
             if self.paged:
-                active = np.zeros((self.n_slots,), bool)
-                active[decoding] = True
                 toks, self._caches = self._decode_p(
                     self.params, self._caches, self._toks, self._pos,
-                    self._tables, jnp.asarray(active),
+                    self._tables, jnp.asarray(lens),
                     (self._skeys, self._temp, self._topk, self._topp))
             else:
                 toks, self._caches = self._decode(
@@ -1479,13 +1491,14 @@ class ContinuousEngine:
                     continue
             if act.is_finished():
                 results[act.request.rid] = self._finish(slot)
-        self._record_step(decoding, prefills, new_tokens)
+        self._record_step(decoding, prefills, new_tokens,
+                          decode_pages=decode_pages)
         self._now = now + 1
         return True
 
     def _record_step(self, active_slots, prefills: int, new_tokens: int,
                      drafted: int = 0, accepted: int = 0,
-                     rewound: int = 0) -> None:
+                     rewound: int = 0, decode_pages: int = 0) -> None:
         """Close the step in progress into a ``ServeStep``.  Host
         arithmetic only: nothing here runs on the device."""
         spans = self._spans
@@ -1516,6 +1529,9 @@ class ContinuousEngine:
             prefix_lookup_tokens=cur[2] - prev[2],
             shared_saved_bytes=shared, cached_blocks=cached,
             drafted=drafted, accepted=accepted, rewound_tokens=rewound,
+            decode_pages=decode_pages,
+            decode_table_pages=(self.n_slots * self._max_blocks
+                                if decode_pages else 0),
             t0=spans.t0, t1=t1, phases=spans.phases,
             admitted=spans.admitted, chunks=spans.chunks,
             decoded=spans.decoded,

@@ -173,3 +173,57 @@ def test_block_bytes_from_shapes_equals_the_slice(arch):
         sliced = (per_k.size * per_k.dtype.itemsize
                   + per_v.size * per_v.dtype.itemsize)
         assert store.block_bytes == sliced
+
+
+def test_decode_span_carries_the_live_pages(profiled):
+    """The ``engine.decode`` span's ``pages`` stat is its step record's
+    ``decode_pages``; speculative rounds run no decode program and count
+    none."""
+    mode, eng, spans, _ = profiled
+    pages = {s.step: s.decode_pages for s in eng.telemetry.steps}
+    decodes = [(st["step"], st["pages"]) for n, _, _, st in spans
+               if n == "engine.decode"]
+    assert bool(decodes) == (mode == "chunk")
+    for step, n in decodes:
+        assert n == pages[step] > 0
+    assert sum(pages.values()) == sum(n for _, n in decodes)
+
+
+def test_decode_lens_cover_only_the_decoding_lanes():
+    """A latent-attention model in a mixed run (free lanes, decoding lanes
+    and lanes mid chunked prefill in one step): the decode program is
+    handed each decoding lane's resident length, its position + 1, and 0
+    for every other lane, and the step record counts the pages those
+    lengths hold against the whole tables' pages."""
+    cfg = get("deepseek-v2-lite-16b").reduced()
+    params = jax.jit(lambda key: lm.init_params(cfg, key, jnp.float32))(
+        jax.random.PRNGKey(0))
+    eng = ContinuousEngine(cfg, params, kv_len=64, n_slots=3, paged=True,
+                           prefill_chunk=8)
+    handed = []
+    program = eng._decode_prog
+
+    def spy(params, caches, toks, pos, tables, lens, sample_args=None):
+        handed.append(jax.device_get(lens))
+        return program(params, caches, toks, pos, tables, lens, sample_args)
+
+    eng._decode_prog = spy
+    for rid, (n, new, at) in enumerate(((5, 12, 0), (30, 6, 2),
+                                        (20, 4, 9))):
+        eng.submit(list(range(1, n + 1)), new, rid=rid, arrival=at)
+    eng.run()
+    steps = [s for s in eng.telemetry.steps if s.active_slots]
+    assert len(steps) == len(handed)
+    bs, width = eng.block_size, eng._max_blocks
+    mixed = 0
+    for rec, lens in zip(steps, handed):
+        idle = sorted(set(range(eng.n_slots)) - set(rec.active_slots))
+        assert list(lens[idle]) == [0] * len(idle)
+        assert sorted(lens[list(rec.active_slots)] - 1) == \
+            sorted(p for _, p in rec.decoded)
+        assert rec.decode_pages == sum(-(-int(n) // bs) for n in lens)
+        assert rec.decode_table_pages == eng.n_slots * width
+        partial = [c for c in rec.chunks if c[1] + c[2] < c[3]]
+        mixed += bool(partial) and \
+            len(rec.active_slots) + len(partial) < eng.n_slots
+    assert mixed

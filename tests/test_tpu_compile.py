@@ -151,7 +151,7 @@ def _engine_programs(one_chip, cfg, kv_len: int, lanes: int):
     lane_args = tree((eng._skeys, eng._temp, eng._topk, eng._topp))
     decode = eng._decode_prog.lower(
         params, caches, tree(eng._toks), tree(eng._pos), tree(eng._tables),
-        sds(jax.ShapeDtypeStruct((lanes,), jnp.bool_)), lane_args).compile()
+        i32(lanes), lane_args).compile()
     first = tree((eng._skeys[0], eng._temp[0], eng._topk[0], eng._topp[0]))
     chunk = eng._chunk.lower(
         params, caches, i32(1, 256), i32(), {"global": i32(kv_len // 16)},
@@ -245,3 +245,63 @@ def test_moe_step_runs_the_grouped_matmul_in_place(moe_steps, step, rows):
     assert mem.alias_size_in_bytes >= pool_bytes
     assert [x for x in _pool_ops(hlo, n_pages)
             if not _memory_space_move(x)] == []
+
+
+def test_mla_decode_reads_the_pools_in_place(moe_steps):
+    """The decode program attends through the ``mla_decode`` kernel, which
+    reads each lane's pages of the layer from the stacked pools itself:
+    no op holds the gathered ``[lanes x table width, 16, width]`` pages or
+    one layer's pool sliced out of the stack, and the program's
+    temporaries stay under the 103,082,496 bytes the full-width gather
+    needed (24 lanes x 256 pages)."""
+    programs, _, n_pages = moe_steps
+    compiled = programs["decode"]
+    hlo = compiled.as_text()
+    assert re.search(r"%mla_decode[.\d]* = .*custom_call_target="
+                     r"\"tpu_custom_call\"", hlo)
+    gathered = 24 * (4096 // 16)
+    for line in hlo.splitlines():
+        shape = line.split(" = ")[-1].split(" ")[0]
+        assert not re.match(rf"bf16\[{gathered},16,(512|128)\]", shape), line
+        assert not re.match(rf"bf16\[(1,)?{n_pages},16,(512|128)\]", shape) \
+            or " dynamic-update-slice(" in line \
+            or re.match(r"\s*%?\S+ = \S+ (parameter|get-tuple-element|"
+                        r"copy-start|copy-done)\(", line), line
+    assert compiled.memory_analysis().temp_size_in_bytes < 103_082_496
+
+
+def test_mha_decode_ignores_the_context_lens(paged_steps, one_chip,
+                                             monkeypatch):
+    """minicpm-2b's decode program compiles to the same ops, shapes
+    included, whether or not ``lm.forward`` is handed the lanes' context
+    lengths: only MLA layers read them."""
+    import dataclasses
+
+    from repro.configs import get
+    from repro.models import lm
+    from repro.serve import engine as engine_mod
+
+    programs, _, _ = paged_steps
+    forward = lm.forward
+    monkeypatch.setattr(lm, "forward", lambda *a, context_lens=None, **k:
+                        forward(*a, **k))
+    monkeypatch.setattr(engine_mod, "_donates", lambda: True)
+    cfg = dataclasses.replace(get("minicpm-2b"), n_layers=4)
+    eng = engine_mod.ContinuousEngine(
+        cfg, None, kv_len=2048, n_slots=4, dtype=jnp.bfloat16, paged=True,
+        prefill_chunk=256, block_size=16)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    tree = lambda t: jax.tree.map(sds, t)
+    params = tree(jax.eval_shape(lambda: lm.init_params(
+        cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+    plain = eng._decode_prog.lower(
+        params, tree(eng._caches), tree(eng._toks), tree(eng._pos),
+        tree(eng._tables), sds(jax.ShapeDtypeStruct((4,), jnp.int32)),
+        tree((eng._skeys, eng._temp, eng._topk, eng._topp))).compile()
+
+    def ops(compiled):
+        found = re.findall(r"= (\S+) ([\w-]+)\(", compiled.as_text())
+        return sorted((op, re.sub(r"\{[^}]*\}", "", shape))
+                      for shape, op in found)
+
+    assert ops(programs["decode"]) == ops(plain)
