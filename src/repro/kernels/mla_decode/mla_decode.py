@@ -20,10 +20,10 @@ nothing and gets zeros.
   pool's padding columns are zero, so they add nothing.
 * The grid is one step per lane.  A lane walks its ``cdiv(n, T)`` blocks
   of ``T = pages * bs`` rows in a loop with a traced trip count, so it
-  stops at its own length: positions past ``n`` in its last block are
-  masked, and nothing past that block is read.  Each block's pages are
-  DMAed row for row into one of two VMEM buffers while the other is
-  computed on (double buffering).
+  stops at its own length: only the ``cdiv(n, bs)`` pages that hold its
+  rows are read, and positions past ``n`` in its last block are masked.
+  Each block's pages are DMAed row for row into one of two VMEM buffers
+  while the other is computed on (double buffering).
 * An online softmax keeps the running max, sum and ``[H, R]`` output in
   float32; the probabilities meet ``ckv`` in the pools' type, as the XLA
   path rounds its probabilities to the serve type.
@@ -58,27 +58,41 @@ def _kernel(layer_ref, tables_ref, lens_ref, q_ref, qr_ref, ckv_hbm, kr_hbm,
     n = lens_ref[b]
     bs = ckv_hbm.shape[2]
     rows = pages * bs
-    n_blocks = (n + rows - 1) // rows
+    n_pages = (n + bs - 1) // bs
+    n_blocks = (n_pages + pages - 1) // pages
 
-    def copies(blk, slot):
-        """The DMAs of block ``blk``'s pages into buffer ``slot``."""
-        out = []
+    @pl.when(b == 0)
+    def _clear():
+        # rows of a last block past the lane's pages are masked, but
+        # 0 * NaN is NaN in the probabilities' matmul: the buffers start
+        # finite, and later hold only finite rows copied from the pools
+        ckv_buf[...] = jnp.zeros(ckv_buf.shape, ckv_buf.dtype)
+        kr_buf[...] = jnp.zeros(kr_buf.shape, kr_buf.dtype)
+
+    def each_copy(blk, slot, act):
+        """``act`` on the DMAs of block ``blk``'s live pages into buffer
+        ``slot``; a last block's pages past the lane's length are not
+        read."""
         for j in range(pages):
-            page = tables_ref[b * width + jnp.minimum(blk * pages + j,
-                                                      width - 1)]
-            dst = pl.ds(j * bs, bs)
-            out.append(pltpu.make_async_copy(
-                ckv_hbm.at[layer, page], ckv_buf.at[slot, dst],
-                sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                kr_hbm.at[layer, page], kr_buf.at[slot, dst],
-                sems.at[1, slot]))
-        return out
+            i = blk * pages + j
+
+            @pl.when(i < n_pages)
+            def _page():
+                page = tables_ref[b * width + i]
+                dst = pl.ds(j * bs, bs)
+                act(pltpu.make_async_copy(
+                    ckv_hbm.at[layer, page], ckv_buf.at[slot, dst],
+                    sems.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    kr_hbm.at[layer, page], kr_buf.at[slot, dst],
+                    sems.at[1, slot]))
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
 
     @pl.when(n_blocks > 0)
     def _first():
-        for c in copies(0, 0):
-            c.start()
+        each_copy(0, 0, start)
 
     q = q_ref[...]                                          # [H, R]
     qr = qr_ref[...]                                        # [H, Kw]
@@ -91,11 +105,9 @@ def _kernel(layer_ref, tables_ref, lens_ref, q_ref, qr_ref, ckv_hbm, kr_hbm,
 
         @pl.when(blk + 1 < n_blocks)
         def _next():
-            for c in copies(blk + 1, 1 - slot):
-                c.start()
+            each_copy(blk + 1, 1 - slot, start)
 
-        for c in copies(blk, slot):
-            c.wait()
+        each_copy(blk, slot, wait)
         ckv = ckv_buf[slot]                                 # [T, R]
         s = (lax.dot_general(q, ckv, nt, preferred_element_type=jnp.float32)
              + lax.dot_general(qr, kr_buf[slot], nt,

@@ -929,8 +929,8 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
       past them.
     context_lens: [B] paged decode only — the rows each lane has resident
       (``pos + 1``), 0 for a lane that is not decoding (free, or mid
-      chunked prefill).  Only MLA layers read it: their decode kernel
-      reads those rows and no others.  None reads ``pos + 1`` everywhere.
+      chunked prefill).  Only MLA layers read it: those rows and no others
+      are attended.  None reads ``pos + 1``, every lane decoding.
     layer_cap: run only the first ``layer_cap`` decoder layers (rounded
       *up* to whole cycle repeats within a segment, so a heterogeneous
       cycle is never split) before the shared final norm + unembed — the

@@ -158,9 +158,10 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables, layer,
 
     Decode is lowered per platform (``_decode_attend``): on a TPU the
     ``mla_decode`` kernel reads each lane's first ``context_lens[b]`` rows
-    (``pos + 1`` when not given) and nothing else; elsewhere, and for a
-    chunk, ``_gathered_attend`` gathers the lane's whole logical view in
-    ascending position order — the layout the dense cache stores (slot ==
+    (``pos + 1``, every lane decoding, when not given: a caller outside
+    the engine) and nothing else; elsewhere, and for a chunk,
+    ``_gathered_attend`` gathers the lane's whole logical view, masked
+    past the same lengths, in ascending position order — the layout the dense cache stores (slot ==
     position), so with the engine's ``kv_len == max_blocks * block_size``
     guarantee the decode arithmetic is exactly ``_mla_decode``'s over
     identical operands.
@@ -185,7 +186,7 @@ def _mla_paged(cfg, p, x, h, positions, cache, tables, layer,
     if S == 1:
         lens = pos + 1 if context_lens is None else context_lens
         o_lat = _decode_attend(cfg, q_lat[:, 0], q_rope[:, 0], ckv_pages,
-                               krope_pages, tables, lens, layer, pos,
+                               krope_pages, tables, lens, layer,
                                x.dtype)[:, None]
     else:
         o_lat = _gathered_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages,
@@ -223,25 +224,28 @@ def _gathered_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages, tables,
 
 
 def _decode_attend(cfg, q_lat, q_rope, ckv_pages, krope_pages, tables, lens,
-                   layer, pos, dtype):
+                   layer, dtype):
     """One decode row per lane (q_lat [B, H, R], q_rope [B, H, rope]) ->
-    o_lat [B, H, R].  Where the program is lowered for a TPU, the
-    ``mla_decode`` kernel reads lane ``b``'s first ``lens[b]`` rows in
-    place from the stacked pools (none for ``lens[b] == 0``, a lane that
-    is not decoding); elsewhere the gather path attends up to ``pos + 1``,
-    the arithmetic the CPU's token-identity tests pin."""
+    o_lat [B, H, R] over lane ``b``'s first ``lens[b]`` rows, its length
+    after this step's write (``pos + 1``) where it decodes and 0 where it
+    does not.  Where the program is lowered for a TPU, the ``mla_decode``
+    kernel reads those rows in place from the stacked pools (none for
+    ``lens[b] == 0``); elsewhere the gather path masks past them, the
+    arithmetic the CPU's token-identity tests pin.  A lane of length 0
+    gets zeros from the kernel and a mean of masked rows from the gather;
+    neither is read."""
     from repro.kernels.mla_decode import ops
 
-    def kernel(ql, qr, cp, kp, t, n, lyr, ps):
+    def kernel(ql, qr, cp, kp, t, n, lyr):
         return ops.mla_decode(ql, qr, cp, kp, t, n, lyr,
                               scale=softmax_scale(cfg))
 
-    def gathered(ql, qr, cp, kp, t, n, lyr, ps):
+    def gathered(ql, qr, cp, kp, t, n, lyr):
         return _gathered_attend(cfg, ql[:, None], qr[:, None], cp, kp, t,
-                                lyr, ps + 1, ps[:, None], dtype)[:, 0]
+                                lyr, n, (n - 1)[:, None], dtype)[:, 0]
 
     return lax.platform_dependent(q_lat, q_rope, ckv_pages, krope_pages,
-                                  tables, lens, layer, pos, tpu=kernel,
+                                  tables, lens, layer, tpu=kernel,
                                   default=gathered)
 
 

@@ -93,12 +93,10 @@ class ServeStep:
     accepted: int = 0
     rewound_tokens: int = 0
     # paged decode: the pages the step's decode had to read, sum of
-    # ceil(len / block_size) over the decoding lanes' resident lengths,
-    # and the pages of every lane's whole table (lanes x table width),
-    # which a decode that gathers the full width reads; 0 without a
-    # paged decode this step
+    # ceil(len / block_size) over the decoding lanes' resident lengths
+    # (a decode that gathers the full width reads every lane's whole
+    # table, lanes x table width); 0 without a paged decode this step
     decode_pages: int = 0
-    decode_table_pages: int = 0
     # where the step's host time went and what it did (see StepSpans):
     # perf_counter at its start and end, host seconds per phase span,
     # the rids admitted, the prompt chunks run ((rid, start, valid,
@@ -227,7 +225,7 @@ class ServeTelemetry:
                     shared_saved_bytes: int = 0,
                     cached_blocks: int = 0, drafted: int = 0,
                     accepted: int = 0, rewound_tokens: int = 0,
-                    decode_pages: int = 0, decode_table_pages: int = 0,
+                    decode_pages: int = 0,
                     t0: float = 0.0, t1: float = 0.0, phases: dict = None,
                     admitted=(), chunks=(), decoded=(),
                     experts=None) -> None:
@@ -245,7 +243,6 @@ class ServeTelemetry:
             cached_blocks=cached_blocks, drafted=drafted,
             accepted=accepted, rewound_tokens=rewound_tokens,
             decode_pages=decode_pages,
-            decode_table_pages=decode_table_pages,
             t0=t0, t1=t1, phases=dict(phases or {}),
             admitted=tuple(admitted), chunks=tuple(chunks),
             decoded=tuple(decoded), experts=experts))

@@ -303,7 +303,7 @@ def make_draft_decode_step(cfg: ModelConfig, draft_layers: int,
             paged_tables=None if g_row is None else g_row[None],
             window_tables=None if w_row is None else w_row[None],
             cross_tables=None if x_row is None else x_row[None],
-            layer_cap=draft_layers)
+            context_lens=pos.reshape(1) + 1, layer_cap=draft_layers)
         caches = lm.lane_merge(cfg, caches, new_sub, slot)
         row = logits[0, -1, :cfg.vocab_size]
         nxt = sampling_mod.sample_token(row, key, temp, topk, topp)
@@ -1530,8 +1530,6 @@ class ContinuousEngine:
             shared_saved_bytes=shared, cached_blocks=cached,
             drafted=drafted, accepted=accepted, rewound_tokens=rewound,
             decode_pages=decode_pages,
-            decode_table_pages=(self.n_slots * self._max_blocks
-                                if decode_pages else 0),
             t0=spans.t0, t1=t1, phases=spans.phases,
             admitted=spans.admitted, chunks=spans.chunks,
             decoded=spans.decoded,

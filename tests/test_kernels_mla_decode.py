@@ -104,3 +104,25 @@ def test_oracle_is_softmax_over_the_live_rows():
                      scale=mla.softmax_scale(cfg))
     np.testing.assert_allclose(np.asarray(want[b]), p @ rows[:n],
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("pages", (1, 8))
+def test_kernel_reads_only_the_live_pages(pages):
+    """Every pool row outside the lanes' live pages set to NaN — the null
+    page, unused pages, other layers — leaves the kernel's output as it
+    was: a last block's pages past a lane's length are never copied, and
+    its buffer rows past them hold finite data."""
+    cfg = CONFIGS["tiny"]()
+    q_lat, q_rope, ckv, kr, tables, lens = _operands(cfg, jnp.float32)
+    layer = 1
+    live = np.zeros(ckv.shape[:2], bool)
+    n_live = -(-np.asarray(lens) // BS)
+    for b, t in enumerate(np.asarray(tables)):
+        live[layer, t[:n_live[b]]] = True
+    keep = jnp.asarray(live)[:, :, None, None]
+    args = dict(scale=mla.softmax_scale(cfg), pages=pages, interpret=True)
+    clean = mla_decode(q_lat, q_rope, ckv, kr, tables, lens, layer, **args)
+    out = mla_decode(q_lat, q_rope, jnp.where(keep, ckv, jnp.nan),
+                     jnp.where(keep, kr, jnp.nan), tables, lens, layer,
+                     **args)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))

@@ -194,7 +194,7 @@ def test_decode_lens_cover_only_the_decoding_lanes():
     and lanes mid chunked prefill in one step): the decode program is
     handed each decoding lane's resident length, its position + 1, and 0
     for every other lane, and the step record counts the pages those
-    lengths hold against the whole tables' pages."""
+    lengths hold, at most the whole tables' pages."""
     cfg = get("deepseek-v2-lite-16b").reduced()
     params = jax.jit(lambda key: lm.init_params(cfg, key, jnp.float32))(
         jax.random.PRNGKey(0))
@@ -222,7 +222,7 @@ def test_decode_lens_cover_only_the_decoding_lanes():
         assert sorted(lens[list(rec.active_slots)] - 1) == \
             sorted(p for _, p in rec.decoded)
         assert rec.decode_pages == sum(-(-int(n) // bs) for n in lens)
-        assert rec.decode_table_pages == eng.n_slots * width
+        assert rec.decode_pages <= eng.n_slots * width
         partial = [c for c in rec.chunks if c[1] + c[2] < c[3]]
         mixed += bool(partial) and \
             len(rec.active_slots) + len(partial) < eng.n_slots

@@ -251,7 +251,9 @@ def test_mla_decode_reads_the_pools_in_place(moe_steps):
     """The decode program attends through the ``mla_decode`` kernel, which
     reads each lane's pages of the layer from the stacked pools itself:
     no op holds the gathered ``[lanes x table width, 16, width]`` pages or
-    one layer's pool sliced out of the stack, and the program's
+    one layer's pool sliced out of the stack or copied into another layout
+    (a move between memory spaces, in the same layout, is let through as
+    in the grouped-matmul test), and the program's
     temporaries stay under the 103,082,496 bytes the full-width gather
     needed (24 lanes x 256 pages)."""
     programs, _, n_pages = moe_steps
@@ -265,8 +267,9 @@ def test_mla_decode_reads_the_pools_in_place(moe_steps):
         assert not re.match(rf"bf16\[{gathered},16,(512|128)\]", shape), line
         assert not re.match(rf"bf16\[(1,)?{n_pages},16,(512|128)\]", shape) \
             or " dynamic-update-slice(" in line \
-            or re.match(r"\s*%?\S+ = \S+ (parameter|get-tuple-element|"
-                        r"copy-start|copy-done)\(", line), line
+            or _memory_space_move(line) \
+            or re.match(r"\s*%?\S+ = \S+ (parameter|get-tuple-element)\(",
+                        line), line
     assert compiled.memory_analysis().temp_size_in_bytes < 103_082_496
 
 
